@@ -68,10 +68,10 @@ class AdaptiveCodec(PageCodec):
     members = MEMBERS
     member_names = MEMBER_NAMES
 
-    def init_pools(self, n_layers, n_pages, kvh, page, dh):
+    def init_pools(self, n_pages, kvh, page, dh):
         return AdaptiveKVPages(
-            jnp.zeros((n_layers, n_pages), jnp.uint8),
-            *(m.init_pools(n_layers, n_pages, kvh, page, dh)
+            jnp.zeros((n_pages,), jnp.uint8),
+            *(m.init_pools(n_pages, kvh, page, dh)
               for m in self.members))
 
     def _compress(self, k, v, fused: bool):

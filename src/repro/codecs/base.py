@@ -8,7 +8,8 @@ algorithm fits the page framework — the framework needs exactly the
 five capabilities below, nothing else:
 
   * ``init_pools``            — allocate the device-resident page pools
-    (an arbitrary pytree whose leaves lead with ``[n_layers, n_pages]``);
+    (an arbitrary pytree whose leaves lead with ``[n_pages]``: one page
+    id names one layer's page, so all layers share the pool);
   * ``compress_kv_pages``     — turn exact f32 KV page blocks into the
     codec's compressed form (the batched page-fill path);
   * ``decompress_pages``      — the inverse, used by the gather-dequant
@@ -48,10 +49,10 @@ class PageCodec:
     """Interface every page codec implements (see the module docstring).
 
     Shape conventions: KV page blocks are f32 ``[n, KVH, page, D]`` (one
-    leading block axis); pool pytree leaves lead with
-    ``[n_layers, n_pages]``.  All methods must be jit-traceable — they
-    run inside the engines' fused dispatches — and instances must be
-    stateless singletons (they are jit *static* arguments).
+    leading block axis); pool pytree leaves lead with ``[n_pages]``.
+    All methods must be jit-traceable — they run inside the engines'
+    fused dispatches — and instances must be stateless singletons
+    (they are jit *static* arguments).
     """
 
     name: str = "?"
@@ -78,9 +79,8 @@ class PageCodec:
 
     # -- required ------------------------------------------------------------
 
-    def init_pools(self, n_layers: int, n_pages: int, kvh: int,
-                   page: int, dh: int):
-        """Zero-state page pools: a pytree, leaves [L, P, ...]."""
+    def init_pools(self, n_pages: int, kvh: int, page: int, dh: int):
+        """Zero-state page pools: a pytree, leaves [P, ...]."""
         raise NotImplementedError
 
     def compress_kv_pages(self, k: jax.Array, v: jax.Array):

@@ -28,8 +28,8 @@ class BDICodec(PageCodec):
     lossless = False               # int8 quantization: |err| <= scale/2
     has_fused_kernels = True       # Pallas row codec + paged attention
 
-    def init_pools(self, n_layers, n_pages, kvh, page, dh):
-        shp = (n_layers, n_pages, kvh, page)
+    def init_pools(self, n_pages, kvh, page, dh):
+        shp = (n_pages, kvh, page)
         return ref.CompressedKVPages(
             kd=jnp.zeros(shp + (dh,), jnp.int8),
             kb=jnp.zeros(shp, jnp.float32),

@@ -40,7 +40,7 @@ from .base import PageCodec, register
 
 
 class FPCKVPages(NamedTuple):
-    """Class-planar FPC pages (pool: leading [L, P]; fresh: [n]).
+    """Class-planar FPC pages (pool: leading [P]; fresh: [n]).
 
     Per side: 2-bit class plane (u8) [..., KVH, page, D], class-2 top
     halves (u16, zero elsewhere), class-3 exception payloads (f32, zero
@@ -92,8 +92,8 @@ class FPCCodec(PageCodec):
     ulp_stable_sizes = False       # sizes read exact mantissa bits
     has_fused_kernels = False
 
-    def init_pools(self, n_layers, n_pages, kvh, page, dh):
-        shp = (n_layers, n_pages, kvh, page, dh)
+    def init_pools(self, n_pages, kvh, page, dh):
+        shp = (n_pages, kvh, page, dh)
         return FPCKVPages(
             kcls=jnp.zeros(shp, jnp.uint8),
             khi=jnp.zeros(shp, jnp.uint16),

@@ -36,9 +36,9 @@ class GBDICodec(PageCodec):
     has_fused_kernels = False      # no fused attention kernel
     has_fused_fill = True          # Pallas compress/decompress pair
 
-    def init_pools(self, n_layers, n_pages, kvh, page, dh):
-        shp = (n_layers, n_pages, kvh, page)
-        bshp = (n_layers, n_pages, K_BASES)
+    def init_pools(self, n_pages, kvh, page, dh):
+        shp = (n_pages, kvh, page)
+        bshp = (n_pages, K_BASES)
         return GBDIKVPages(
             kd=jnp.zeros(shp + (dh,), jnp.int8),
             kbs=jnp.zeros(bshp, jnp.float32),

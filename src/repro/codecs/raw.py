@@ -32,10 +32,10 @@ class RawCodec(PageCodec):
     name = "raw"
     lossless = True
 
-    def init_pools(self, n_layers, n_pages, kvh, page, dh):
+    def init_pools(self, n_pages, kvh, page, dh):
         # distinct buffers per field: the engines donate the pool pytree
         # into jitted updates, and aliased leaves would donate twice
-        shp = (n_layers, n_pages, kvh, page, dh)
+        shp = (n_pages, kvh, page, dh)
         return RawKVPages(jnp.zeros(shp, jnp.float32),
                           jnp.zeros(shp, jnp.float32))
 

@@ -66,10 +66,10 @@ class ZeroRepCodec(PageCodec):
     name = "zero"
     lossless = True
 
-    def init_pools(self, n_layers, n_pages, kvh, page, dh):
+    def init_pools(self, n_pages, kvh, page, dh):
         # distinct buffers per field: the engines donate the pool pytree
         # into jitted updates, and aliased leaves would donate twice
-        shp = (n_layers, n_pages, kvh, page)
+        shp = (n_pages, kvh, page)
 
         def side():
             return (jnp.zeros(shp, jnp.int8),

@@ -27,26 +27,10 @@ MODEL = "model"
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (no replication checking).
-
-    jax moved ``shard_map`` from ``jax.experimental`` to the top level and
-    renamed its ``check_rep`` kwarg to ``check_vma`` along the way; this
-    wrapper resolves whichever spelling the installed jax provides so the
-    compressed collectives and MoE paths run on the pinned 0.4.x leg and
-    the latest-canary leg alike.
-    """
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    kw = {}
-    if "check_vma" in params:
-        kw["check_vma"] = False
-    elif "check_rep" in params:
-        kw["check_rep"] = False
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    """``jax.shard_map`` without replication checking, as the compressed
+    collectives and the MoE path call it."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def current_mesh() -> Mesh | None:

@@ -3,29 +3,17 @@
 Lives in its own module (not ``ops``) so the kernel modules can resolve
 their ``interpret`` default without importing ``ops`` back (cycle).
 
-Resolution order:
-  1. ``REPRO_PALLAS_INTERPRET`` env var ("1"/"true"/"0"/"false") — explicit
-     override for debugging compiled kernels or forcing interpret in CI;
-  2. otherwise: compiled on TPU backends, interpret everywhere else.
+Kernels compile natively on TPU backends and run in interpret mode
+everywhere else; the backend alone decides.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
-
-_TRUTHY = {"1", "true", "yes", "on"}
-_FALSY = {"0", "false", "no", "off"}
 
 
 def default_interpret() -> bool:
     """Whether Pallas kernels should run in interpret mode by default."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if env in _TRUTHY:
-        return True
-    if env in _FALSY:
-        return False
     return jax.default_backend() != "tpu"
 
 

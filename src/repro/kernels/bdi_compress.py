@@ -141,7 +141,7 @@ def bdi_compress(x: jax.Array, *, block_n: int = 8,
     """x f32 [N, T] -> (deltas i8, base f32, scale f32, maskp u8, enc i32).
 
     ``interpret=None`` resolves from the backend (compiled on TPU,
-    interpret elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides).
+    interpret elsewhere).
     """
     return _bdi_compress(x, block_n=block_n,
                          interpret=resolve_interpret(interpret))

@@ -45,7 +45,7 @@ def bdi_decompress(deltas: jax.Array, base: jax.Array, scale: jax.Array,
     """deltas int8 [N, T], base/scale f32 [N, 1], maskp uint8 [N, T//8].
 
     ``interpret=None`` resolves from the backend (compiled on TPU,
-    interpret elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides).
+    interpret elsewhere).
     """
     return _bdi_decompress(deltas, base, scale, maskp, block_n=block_n,
                            interpret=resolve_interpret(interpret))

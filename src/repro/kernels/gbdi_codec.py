@@ -50,7 +50,7 @@ _Q4MAX = 7.0  # signed 4-bit delta range used by width class 1
 
 
 class GBDIKVPages(NamedTuple):
-    """Multi-base compressed KV pages (pool: leading [L, P]; fresh: [n]).
+    """Multi-base compressed KV pages (pool: leading [P]; fresh: [n]).
 
     Per side: int8 deltas [..., KVH, page, D], f32 bases [..., K_BASES],
     int8 base id [..., KVH, page], f32 scale [..., KVH, page], int8 width
@@ -148,17 +148,23 @@ def decode_pages_ref(d, bases, bid, sc) -> jax.Array:
                                   sc[:, :, None])
 
 
+# The kernels see the [pages, K] bases as [pages, 1, K]: one page's
+# (1, K) block then spans the array's last two dims, which is the block
+# shape the TPU compiler accepts for a K-wide minor dim.
+_BASES_BLOCK = pl.BlockSpec((1, 1, K_BASES), lambda i: (i, 0, 0))
+
+
 def _gbdi_compress_kernel(x_ref, d_ref, bases_ref, bid_ref, sc_ref, wid_ref):
     d, bases, bid, sc, wid = _encode_page(x_ref[...].astype(jnp.float32))
     d_ref[...] = d
-    bases_ref[...] = bases
+    bases_ref[0] = bases
     bid_ref[...] = bid
     sc_ref[...] = sc
     wid_ref[...] = wid
 
 
 def _gbdi_decompress_kernel(d_ref, bases_ref, bid_ref, sc_ref, out_ref):
-    out_ref[...] = _decode_page(d_ref[...], bases_ref[...],
+    out_ref[...] = _decode_page(d_ref[...], bases_ref[0],
                                 bid_ref[...].astype(jnp.int32), sc_ref[...])
 
 
@@ -169,8 +175,10 @@ def gbdi_compress(x: jax.Array, *, rows_per_page: int,
 
     ``interpret=None`` resolves from the backend.
     """
-    return _gbdi_compress(x, rows_per_page=rows_per_page,
-                          interpret=resolve_interpret(interpret))
+    d, bases, bid, sc, wid = _gbdi_compress(
+        x, rows_per_page=rows_per_page,
+        interpret=resolve_interpret(interpret))
+    return d, bases[:, 0], bid, sc, wid
 
 
 @functools.partial(jax.jit, static_argnames=("rows_per_page", "interpret"))
@@ -186,14 +194,14 @@ def _gbdi_compress(x: jax.Array, *, rows_per_page: int, interpret: bool):
         in_specs=[pl.BlockSpec((rows_per_page, d), row)],
         out_specs=[
             pl.BlockSpec((rows_per_page, d), row),
-            pl.BlockSpec((1, K_BASES), row),
+            _BASES_BLOCK,
             pl.BlockSpec((rows_per_page, 1), row),
             pl.BlockSpec((rows_per_page, 1), row),
             pl.BlockSpec((rows_per_page, 1), row),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, d), jnp.int8),
-            jax.ShapeDtypeStruct((pages, K_BASES), jnp.float32),
+            jax.ShapeDtypeStruct((pages, 1, K_BASES), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.int8),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.int8),
@@ -207,7 +215,8 @@ def gbdi_decompress(d: jax.Array, bases: jax.Array, bid: jax.Array,
                     interpret: bool | None = None) -> jax.Array:
     """(d i8 [N, D], bases f32 [n, K], bid i8 [N, 1], sc f32 [N, 1]) ->
     f32 [N, D] rows, pairing :func:`gbdi_compress`."""
-    return _gbdi_decompress(d, bases, bid, sc, rows_per_page=rows_per_page,
+    return _gbdi_decompress(d, bases[:, None, :], bid, sc,
+                            rows_per_page=rows_per_page,
                             interpret=resolve_interpret(interpret))
 
 
@@ -224,7 +233,7 @@ def _gbdi_decompress(d: jax.Array, bases: jax.Array, bid: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((rows_per_page, dd), row),
-            pl.BlockSpec((1, K_BASES), row),
+            _BASES_BLOCK,
             pl.BlockSpec((rows_per_page, 1), row),
             pl.BlockSpec((rows_per_page, 1), row),
         ],

@@ -4,8 +4,7 @@ Handle padding to block multiples, dtype/layout adaptation, and backend
 dispatch: on TPU the Pallas path compiles natively; elsewhere kernels run in
 ``interpret=True`` mode (the kernel body executed on CPU for validation).
 The policy lives in :func:`default_interpret` (re-exported from
-``kernels._backend``): False on TPU backends, True otherwise, with a
-``REPRO_PALLAS_INTERPRET`` env override.
+``kernels._backend``): False on TPU backends, True otherwise.
 
 This module is the **BDI instance's** kernel surface: the serving stack
 never imports it directly anymore — it consumes the

@@ -130,11 +130,27 @@ def _paged_attn_tail_kernel(pt_ref, len_ref, tlen_ref,     # scalar prefetch
         out_ref[0, 0] = acc_ref[...] / l_ref[:, :1]
 
 
-def _expand_scales(pages: CompressedKVPages):
-    """Trailing singleton so the kernel sees [page, 1] tiles that broadcast
-    against [page, d] without relayout."""
-    return (pages.kb[..., None], pages.ks[..., None],
-            pages.vb[..., None], pages.vs[..., None])
+def _table_scales(pages: CompressedKVPages, page_table: jax.Array):
+    """Base/scale of the pages the table names, [B * PMAX, KVH, page, 1].
+
+    The trailing singleton lets the kernel see [page, 1] tiles that
+    broadcast against [page, d] without relayout.  It pads to a full
+    lane tile on TPU, so the scales are gathered first: expanded in
+    place, a pool's scales would take 128x their size.  Block (b, p) of
+    the result is page_table[b, p]'s scales (see ``_scale_map``).
+    """
+    def gather(x):
+        return x[page_table].reshape((-1,) + x.shape[1:])[..., None]
+    return (gather(pages.kb), gather(pages.ks),
+            gather(pages.vb), gather(pages.vs))
+
+
+def _scale_map(pmax: int):
+    """Index map of the gathered scales: row b * PMAX + p; the tail grid
+    step (p == PMAX) clamps to the row's last page, which it never reads."""
+    def index(b_i, h_i, p_i, *_):
+        return (b_i * pmax + jnp.minimum(p_i, pmax - 1), h_i, 0, 0)
+    return index
 
 
 def paged_attention(q: jax.Array, pages: CompressedKVPages,
@@ -143,7 +159,7 @@ def paged_attention(q: jax.Array, pages: CompressedKVPages,
     """q f32 [B, KVH, G, D]; page_table i32 [B, PMAX]; lengths i32 [B].
 
     ``interpret=None`` resolves from the backend (compiled on TPU,
-    interpret elsewhere; ``REPRO_PALLAS_INTERPRET`` overrides).
+    interpret elsewhere).
     """
     return _paged_attention(q, pages, page_table, lengths,
                             interpret=resolve_interpret(interpret))
@@ -154,7 +170,8 @@ def _paged_attention(q, pages, page_table, lengths, *, interpret):
     bsz, kvh, g, d = q.shape
     pmax = page_table.shape[1]
     page = pages.kd.shape[2]
-    kb, ks, vb, vs = _expand_scales(pages)
+    kb, ks, vb, vs = _table_scales(pages, page_table)
+    sc_map = _scale_map(pmax)
 
     def kv_map(b_i, h_i, p_i, pt, ln):
         del ln
@@ -170,11 +187,11 @@ def _paged_attention(q, pages, page_table, lengths, *, interpret):
         in_specs=[
             pl.BlockSpec((1, 1, g, d), q_map),
             pl.BlockSpec((1, 1, page, d), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
             pl.BlockSpec((1, 1, page, d), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), q_map),
         scratch_shapes=[
@@ -213,7 +230,8 @@ def _paged_attention_tail(q, pages, page_table, lengths,
     bsz, kvh, g, d = q.shape
     pmax = page_table.shape[1]
     page = pages.kd.shape[2]
-    kb, ks, vb, vs = _expand_scales(pages)
+    kb, ks, vb, vs = _table_scales(pages, page_table)
+    sc_map = _scale_map(pmax)
 
     def kv_map(b_i, h_i, p_i, pt, ln, tl):
         del ln, tl
@@ -231,11 +249,11 @@ def _paged_attention_tail(q, pages, page_table, lengths,
         in_specs=[
             pl.BlockSpec((1, 1, g, d), bh_map),
             pl.BlockSpec((1, 1, page, d), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
             pl.BlockSpec((1, 1, page, d), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
-            pl.BlockSpec((1, 1, page, 1), kv_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
+            pl.BlockSpec((1, 1, page, 1), sc_map),
             pl.BlockSpec((1, 1, page, d), bh_map),
             pl.BlockSpec((1, 1, page, d), bh_map),
         ],

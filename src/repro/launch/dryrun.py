@@ -1,12 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
-# ^ MUST run before any other import (jax locks device count on first init).
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST run before any other import (jax locks device count and platform
+# on first init).  The dry-run compiles for 512 virtual host devices; on a
+# machine with an accelerator it must still never claim the chip.
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-NOTE: the XLA_FLAGS assignment above intentionally precedes every import —
-jax locks the device count on first initialization.
+NOTE: the XLA_FLAGS / JAX_PLATFORMS assignments above intentionally
+precede every import — jax locks the device count on first initialization.
 
 For train shapes this lowers a full train_step (fwd + bwd + AdamW update)
 under the production sharding rules; for prefill shapes, model.prefill;
@@ -519,8 +522,6 @@ def run(arch: str, shape: str, multi_pod: bool, out: str | None = None,
     print("=== memory_analysis ===")
     print(mem)
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):       # older jax: one dict per program
-        cost = cost[0] if cost else {}
     print("=== cost_analysis (flops/bytes) ===")
     print({k: v for k, v in cost.items()
            if k in ("flops", "bytes accessed", "transcendentals")})

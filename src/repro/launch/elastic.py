@@ -22,21 +22,6 @@ from repro.checkpoint import store
 from repro.distributed import sharding as SH
 
 
-def abstract_mesh(sizes: tuple[int, ...], names: tuple[str, ...]):
-    """Build an ``AbstractMesh`` across jax versions.
-
-    Newer jax takes ``(axis_sizes, axis_names)``; 0.4.x takes a single
-    tuple of ``(name, size)`` pairs.  Audit-only meshes (``reshard_plan``
-    against a topology with no attached devices) go through here so the
-    capacity-planning path works on both CI legs.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
 def reshard_plan(shape_tree, old_mesh: Mesh, new_mesh: Mesh) -> dict:
     """Audit how sharding changes between meshes.
 

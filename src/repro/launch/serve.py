@@ -43,6 +43,11 @@ demos engine snapshot/restore: the engine state is checkpointed
 mid-stream, then restored after the run and driven to completion; the
 report's ``snapshot.restored_match`` confirms token-identical output.
 
+``--smoke`` builds the arch's reduced config (``ArchConfig.reduced``);
+without it the published config is built, full width and full depth.
+``generate(n_layers=N)`` cuts the depth alone, for a run whose weights
+and pools do not fit one device at full depth.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --smoke \
       --prompt-len 16 --gen 16 [--paged | --paged-reference | --scheduler]
@@ -51,7 +56,9 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
+import pathlib
 import time
 
 import jax
@@ -61,7 +68,31 @@ from repro.configs.registry import get_arch
 from repro.models.api import get_model
 
 
-def generate(arch: str, *, smoke: bool = True, batch: int = 4,
+_REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Put JAX's persistent compilation cache at a fixed path and return
+    it: ``JAX_COMPILATION_CACHE_DIR`` where that is set (JAX reads it
+    itself), else ``<repo>/.jax_cache``.  The path is part of the cache
+    key, so it must not move between runs.  Call it from entry points,
+    never at import."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = str(_REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def device_info() -> dict:
+    """The device the run is on, as JAX reports it."""
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
+def generate(arch: str, *, smoke: bool = False, batch: int = 4,
              prompt_len: int = 16, gen: int = 16,
              paged: bool = False, paged_reference: bool = False,
              prefill_chunk: int | None = None,
@@ -85,10 +116,15 @@ def generate(arch: str, *, smoke: bool = True, batch: int = 4,
              tier_host_mb: float | None = None,
              tier_disk_dir: str | None = None,
              persist_cache: str | None = None,
-             multi_turn: int = 0) -> dict:
+             multi_turn: int = 0,
+             n_layers: int | None = None) -> dict:
     cfg = get_arch(arch)
     if smoke:
         cfg = cfg.reduced()
+    if n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    # 512 pages of every layer: a published page takes one pool id per layer
+    pool_pages = 512 * cfg.n_layers
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     key = jax.random.PRNGKey(1)
@@ -125,7 +161,7 @@ def generate(arch: str, *, smoke: bool = True, batch: int = 4,
 
         tel = Telemetry()
         cache = PrefixCache.for_model(cfg, 8)
-        eng = PagedKVEngine(cfg, params, page_size=8, n_pool_pages=512,
+        eng = PagedKVEngine(cfg, params, page_size=8, n_pool_pages=pool_pages,
                             max_batch=1, prefill_chunk=prefill_chunk,
                             prefix_cache=cache, codec=codec, telemetry=tel,
                             cache_decode_pages=True)
@@ -196,7 +232,7 @@ def generate(arch: str, *, smoke: bool = True, batch: int = 4,
             injector = F.FaultInjector(F.FaultSpec(
                 corrupt_page_every=7, corrupt_max=2,
                 garble_decode_every=11, garble_max=2), seed=chaos)
-        eng = PagedKVEngine(cfg, params, page_size=8, n_pool_pages=512,
+        eng = PagedKVEngine(cfg, params, page_size=8, n_pool_pages=pool_pages,
                             max_batch=batch, prefill_chunk=prefill_chunk,
                             prefix_cache=cache, codec=codec,
                             faults=injector, telemetry=tel,
@@ -276,6 +312,7 @@ def generate(arch: str, *, smoke: bool = True, batch: int = 4,
                 f.write(tel.registry.to_prometheus())
         if trace_out is not None:
             tel.tracer.write_chrome_trace(trace_out)
+        eng.debug_validate()
         fin = sched.finished()
         outs = [fin[b].out_tokens for b in range(batch)]
         # first_token_iter stays None when a request retires preempted
@@ -293,6 +330,9 @@ def generate(arch: str, *, smoke: bool = True, batch: int = 4,
                       "reason": str(fin[b].finish_reason)}
                   for b in range(batch)}
         out = {"tokens": outs, "codec": eng.codec.name,
+               "device": device_info(), "n_layers": cfg.n_layers,
+               "use_fused": eng.use_fused,
+               "use_fused_fill": eng.use_fused_fill,
                "kv_compression_ratio": eng.compression_ratio(),
                "stats": eng.stats,
                "sched_stats": sched.stats, "per_request": report,
@@ -335,15 +375,16 @@ def generate(arch: str, *, smoke: bool = True, batch: int = 4,
         if paged_reference:
             from repro.serving.reference import ReferencePagedKVEngine
             eng = ReferencePagedKVEngine(cfg, params, page_size=8,
-                                         n_pool_pages=512, codec=codec)
+                                         n_pool_pages=pool_pages, codec=codec)
             eng.add_requests(reqs)
             for _ in range(gen):
                 for b in range(batch):
                     eng.decode_one(b)
         else:
             from repro.serving.engine import PagedKVEngine
-            eng = PagedKVEngine(cfg, params, page_size=8, n_pool_pages=512,
-                                max_batch=batch, prefill_chunk=prefill_chunk,
+            eng = PagedKVEngine(cfg, params, page_size=8,
+                                n_pool_pages=pool_pages, max_batch=batch,
+                                prefill_chunk=prefill_chunk,
                                 codec=codec)
             eng.add_requests(reqs)      # one chunked-batch prefill pass
             for _ in range(gen):
@@ -442,6 +483,9 @@ def main() -> None:
         epilog=_EPILOG,
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="run the arch's reduced config instead of the "
+                         "published one")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=16)
@@ -534,7 +578,9 @@ def main() -> None:
                          "fully recycled between turns; reports per-turn "
                          "TTFT and tier demotion/promotion counts")
     args = ap.parse_args()
-    out = generate(args.arch, batch=args.batch, prompt_len=args.prompt_len,
+    enable_compile_cache()
+    out = generate(args.arch, smoke=args.smoke, batch=args.batch,
+                   prompt_len=args.prompt_len,
                    gen=args.gen, paged=args.paged,
                    paged_reference=args.paged_reference,
                    prefill_chunk=args.prefill_chunk,

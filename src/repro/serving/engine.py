@@ -50,10 +50,11 @@ every prompt token but the last: the first decode step computes the last
 prompt token's K/V exactly once into the tail (this fixed the historical
 "duplicated last prompt key" oracle quirk — see serving/README.md).
 
-  * The per-layer compressed page pools (``kd/kb/ks/vd/vb/vs``) live as
-    device ``jnp`` arrays for the whole engine lifetime; page publishes
-    scatter into them with donated ``.at[]`` writes — no host round-trips
-    of KV data on the token path.
+  * The compressed page pools (``kd/kb/ks/vd/vb/vs``) live as device
+    ``jnp`` arrays for the whole engine lifetime; page publishes scatter
+    into them with donated ``.at[]`` writes — no host round-trips of KV
+    data on the token path.  All layers share one pool: a page id names
+    one layer's page, and every layer has its own page table.
   * The step embeds the last token of every sequence, runs a
     ``lax.scan`` over the stacked per-layer block params, and finishes
     with the LM head + greedy argmax — one XLA computation per token
@@ -66,8 +67,7 @@ prompt token's K/V exactly once into the tail (this fixed the historical
     fused kernel (BDI: the fused-dequant Pallas kernel,
     ``kernels.paged_attention_tail``) reads the pool in compressed form;
     elsewhere a generic gather-decompress-dense jnp fallback runs inside
-    the same jit (``REPRO_PALLAS_INTERPRET`` / the ``use_fused`` ctor
-    arg override the detection).
+    the same jit (the ``use_fused`` ctor arg overrides the detection).
   * Page-fill compression is batched: every freshly filled tail of every
     layer is compressed in one jitted dispatch
     (:func:`_compress_blocks`), which also computes per-page compressed
@@ -172,10 +172,10 @@ class _Cohort:
 # jitted device steps
 # ---------------------------------------------------------------------------
 
-def _attend_ref(codec, q, pools_l, pt, page_len, tk, tv, tail_len):
+def _attend_ref(codec, q, pools, pt, page_len, tk, tv, tail_len):
     """jnp fallback: gather-then-decompress pages + tail, dense softmax.
 
-    q f32 [S, K, G, D]; pools_l the codec's one-layer page pool pytree
+    q f32 [S, K, G, D]; pools the codec's page pool pytree
     (leaves leading [P]); pt i32 [S, PMAX]; tk/tv f32 [S, K, page, D].
     Gathers compressed bytes first so only [S, PMAX] pages decompress,
     not the whole pool.
@@ -185,7 +185,7 @@ def _attend_ref(codec, q, pools_l, pt, page_len, tk, tv, tail_len):
     page = tk.shape[2]
 
     kg, vg = codec.decompress_pages(
-        jax.tree.map(lambda a: a[pt], pools_l))      # [S,PMAX,K,page,D] f32
+        jax.tree.map(lambda a: a[pt], pools))        # [S,PMAX,K,page,D] f32
     kg = jnp.moveaxis(kg, 2, 1).reshape(s, kvh, pmax * page, d)
     vg = jnp.moveaxis(vg, 2, 1).reshape(s, kvh, pmax * page, d)
     kg = jnp.concatenate([kg, tk], axis=2)           # [S, K, T, D]
@@ -207,9 +207,10 @@ def _decode_core(params, pools, tk, tv, page_table, page_cnt,
                  codec: codecs.PageCodec, use_fused: bool):
     """One greedy decode step for every active sequence, all layers.
 
-    pools: the codec's page-pool pytree with leading layer dim (leaves
-    [L, P, ...]).  tk/tv f32 [L, S, K, page, D] (donated by the jit
-    wrappers; returned updated).  page_table i32 [L, S, PMAX];
+    pools: the codec's page-pool pytree (leaves [P, ...]), read by
+    every layer through its own page table.  tk/tv f32
+    [L, S, K, page, D] (donated by the jit wrappers; returned
+    updated).  page_table i32 [L, S, PMAX];
     page_cnt/last_tok/pos/tail_len i32 [S]; active bool [S].  Returns
     (next_tok [S], tk', tv').
 
@@ -228,7 +229,7 @@ def _decode_core(params, pools, tk, tv, page_table, page_cnt,
                 & active[:, None])                           # [S, page]
 
     def body(x, xs):
-        bp, pools_l, tk_l, tv_l, pt_l = xs
+        bp, tk_l, tv_l, pt_l = xs
         h = L.rmsnorm(bp["ln1"], x, cfg.norm_eps)
         q = L.linear(bp["attn"]["wq"], h)                    # [S, 1, H, Dh]
         k_new = L.linear(bp["attn"]["wk"], h)                # [S, 1, K, Dh]
@@ -246,10 +247,10 @@ def _decode_core(params, pools, tk, tv, page_table, page_cnt,
         hq = q.shape[2]
         qg = q[:, 0].reshape(s, kvh, hq // kvh, dh).astype(jnp.float32)
         if use_fused:
-            ctx = codec.paged_attention_tail(qg, pools_l, pt_l, page_len,
+            ctx = codec.paged_attention_tail(qg, pools, pt_l, page_len,
                                              tk_l, tv_l, tail_len + 1)
         else:
-            ctx = _attend_ref(codec, qg, pools_l, pt_l, page_len,
+            ctx = _attend_ref(codec, qg, pools, pt_l, page_len,
                               tk_l, tv_l, tail_len + 1)
         ctx = ctx.reshape(s, 1, hq, dh).astype(x.dtype)
         x = x + A._proj_out(bp["attn"], ctx)
@@ -257,7 +258,7 @@ def _decode_core(params, pools, tk, tv, page_table, page_cnt,
         x = x + L.mlp(bp["ffn"], h2)
         return x, (tk_l, tv_l)
 
-    xs = (params["blocks"], pools, tk, tv, page_table)
+    xs = (params["blocks"], tk, tv, page_table)
     x, (tk, tv) = jax.lax.scan(body, x, xs)
     x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = L.lm_logits(params["lm_head"], x)[:, 0]         # [S, V]
@@ -325,8 +326,9 @@ def _prefill_core(params, tokens, kscr, vscr, kcan, vcan, offs, *,
     cos, sin = L.rope_angles(qpos, dh, cfg.rope_theta)       # [R, C, dh/2]
     cos_b, sin_b = cos[:, :, None, :], sin[:, :, None, :]
 
-    def body(x, xs):
-        bp, kscr_l, vscr_l, kcan_l, vcan_l = xs
+    def body(carry, bp):
+        x, li, bufs = carry
+        kscr_l, vscr_l, kcan_l, vcan_l = (buf[li] for buf in bufs)
         h = L.rmsnorm(bp["ln1"], x, cfg.norm_eps)
         # one K/V projection per layer through the shared path (per-row
         # positions), feeding both the scratch write and attention
@@ -350,11 +352,17 @@ def _prefill_core(params, tokens, kscr, vscr, kcan, vcan, offs, *,
                             .astype(x.dtype))
         h2 = L.rmsnorm(bp["ln2"], x, cfg.norm_eps)
         x = x + L.mlp(bp["ffn"], h2)
-        return x, (kscr_l, vscr_l, kcan_l, vcan_l)
+        bufs = tuple(jax.lax.dynamic_update_index_in_dim(buf, new, li, 0)
+                     for buf, new in zip(bufs, (kscr_l, vscr_l, kcan_l,
+                                                vcan_l)))
+        return (x, li + 1, bufs), None
 
-    _, (kscr, vscr, kcan, vcan) = jax.lax.scan(
-        body, x, (params["blocks"], kscr, vscr, kcan, vcan))
-    return kscr, vscr, kcan, vcan
+    # the scratch rides the scan carry and is updated in place; as scan
+    # xs/ys it would get fresh stacked output buffers beside the donated
+    # inputs (2 GiB at yi-6b's widths for an 8-row, 1024-token cohort)
+    (_, _, bufs), _ = jax.lax.scan(
+        body, (x, jnp.int32(0), (kscr, vscr, kcan, vcan)), params["blocks"])
+    return bufs
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "page", "codec"),
@@ -414,11 +422,10 @@ def _fill_warm_scratch(kscr, vscr, kcan, vcan, pools, wpt, wlen, *,
     lyr, r, t, kvh, dh = kscr.shape
     wp = wpt.shape[2]
 
-    def deq_layer(pool_l, pt_l):
-        return codec.decompress_pages(
-            jax.tree.map(lambda a: a[pt_l], pool_l))
+    def deq_layer(pt_l):
+        return codec.decompress_pages(jax.tree.map(lambda a: a[pt_l], pools))
 
-    kw, vw = jax.vmap(deq_layer)(pools, wpt)          # [L, R, WP, K, pg, D]
+    kw, vw = jax.vmap(deq_layer)(wpt)                 # [L, R, WP, K, pg, D]
     page = kw.shape[4]
 
     def flat(x):
@@ -481,7 +488,7 @@ def _gather_tail_blocks(tk, tv, slots):
 @functools.partial(jax.jit,
                    static_argnames=("codec", "use_fused", "member_sizes"),
                    donate_argnums=(0,))
-def _publish_blocks(pools, k_blocks, v_blocks, layer_idx, pids, *,
+def _publish_blocks(pools, k_blocks, v_blocks, pids, *,
                     codec: codecs.PageCodec, use_fused: bool = False,
                     member_sizes: bool = False):
     """Compress [n, K, page, D] KV blocks and scatter them into the pools.
@@ -523,8 +530,8 @@ def _publish_blocks(pools, k_blocks, v_blocks, layer_idx, pids, *,
         msizes = jnp.stack(
             [m.page_nbytes(c) for m, c in
              zip(codec.members, codec._member_pages(pg))])
-    pools = jax.tree.map(
-        lambda pool, new: pool.at[layer_idx, pids].set(new), pools, pg)
+    pools = jax.tree.map(lambda pool, new: pool.at[pids].set(new),
+                         pools, pg)
     return pools, nbytes, csums, tags, msizes
 
 
@@ -532,8 +539,7 @@ def _publish_blocks(pools, k_blocks, v_blocks, layer_idx, pids, *,
 def _gather_entry_pages(pools, pids):
     """Gather one entry's per-layer compressed pages (``pids`` i32 [L])
     out of the pools: leaves [L, ...], the tier's demotion payload."""
-    lidx = jnp.arange(pids.shape[0])
-    return jax.tree.map(lambda a: a[lidx, pids], pools)
+    return jax.tree.map(lambda a: a[pids], pools)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -545,9 +551,7 @@ def _promote_scatter(pools, vals, pids):
     model sizes.  Callers pad N to a power of two (rows aimed at pool
     page 0, the padding target) so retrace count stays logarithmic in
     chain length."""
-    lidx = jnp.arange(pids.shape[1])[None, :]
-    return jax.tree.map(lambda pool, v: pool.at[lidx, pids].set(v),
-                        pools, vals)
+    return jax.tree.map(lambda pool, v: pool.at[pids].set(v), pools, vals)
 
 
 # ---------------------------------------------------------------------------
@@ -606,8 +610,7 @@ class PagedKVEngine:
         self.use_fused_fill = want_fused and (
             self.codec.has_fused_kernels or self.codec.has_fused_fill)
         lyr, k, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        self.pools = self.codec.init_pools(lyr, n_pool_pages, k,
-                                           page_size, dh)
+        self.pools = self.codec.init_pools(n_pool_pages, k, page_size, dh)
         self.tail_k = jnp.zeros((lyr, max_batch, k, page_size, dh),
                                 jnp.float32)
         self.tail_v = jnp.zeros_like(self.tail_k)
@@ -1405,14 +1408,13 @@ class PagedKVEngine:
                 blocks = [blocks[j] for j in keep]
         m = len(seqs)
         pids = self._reserve_pages(lyr * m)
-        layer_idx = jnp.asarray(np.repeat(np.arange(lyr), m), jnp.int32)
         # observatory + composite codec: also pull every member's
         # would-be page size out of the same dispatch (what-if sampling)
         want_members = (self.obs is not None
                         and getattr(self.codec, "members", None)
                         is not None)
         self.pools, nbytes, csums, tags, msizes = _publish_blocks(
-            self.pools, k_blocks, v_blocks, layer_idx,
+            self.pools, k_blocks, v_blocks,
             jnp.asarray(pids, jnp.int32), codec=self.codec,
             use_fused=self.use_fused_fill, member_sizes=want_members)
         # 1 sync per publish

@@ -117,9 +117,9 @@ _checksum_jit = jax.jit(page_checksums)
 
 
 @jax.jit
-def _gather_checksums(pools, layer_idx, pids):
-    """Checksum pool pages ``(layer_idx[j], pids[j])`` in one dispatch."""
-    return page_checksums(jax.tree.map(lambda a: a[layer_idx, pids], pools))
+def _gather_checksums(pools, pids):
+    """Checksum pool pages ``pids[j]`` in one dispatch."""
+    return page_checksums(jax.tree.map(lambda a: a[pids], pools))
 
 
 def pair_checksums(engine, pairs: list[tuple[int, int]]) -> np.ndarray:
@@ -130,20 +130,18 @@ def pair_checksums(engine, pairs: list[tuple[int, int]]) -> np.ndarray:
     in the largest verification batch); numpy pools gather host-side and
     checksum at the exact length.
     """
-    la = np.asarray([p[0] for p in pairs], np.int32)
     pa = np.asarray([p[1] for p in pairs], np.int32)
     leaves = jax.tree.leaves(engine.pools)
     if isinstance(leaves[0], np.ndarray):
-        pg = jax.tree.map(lambda a: jnp.asarray(a[la, pa]), engine.pools)
+        pg = jax.tree.map(lambda a: jnp.asarray(a[pa]), engine.pools)
         return np.asarray(_checksum_jit(pg))
     n = len(pairs)
     cap = 1
     while cap < n:
         cap *= 2
-    lp = np.zeros(cap, np.int32)
-    pp = np.zeros(cap, np.int32)          # (0, 0): the padding page
-    lp[:n], pp[:n] = la, pa
-    out = _gather_checksums(engine.pools, jnp.asarray(lp), jnp.asarray(pp))
+    pp = np.zeros(cap, np.int32)          # 0: the padding page
+    pp[:n] = pa
+    out = _gather_checksums(engine.pools, jnp.asarray(pp))
     return np.asarray(out)[:n]
 
 
@@ -289,17 +287,17 @@ class FaultInjector:
         codec leaf).  Works on device jnp pools (functional ``.at[]``
         write) and host numpy pools (in-place) alike."""
         leaves, treedef = jax.tree_util.tree_flatten(engine.pools)
-        li = next(i for i, lf in enumerate(leaves) if lf[layer, pid].size)
-        pg = np.asarray(leaves[li][layer, pid])
+        li = next(i for i, lf in enumerate(leaves) if lf[pid].size)
+        pg = np.asarray(leaves[li][pid])
         raw = bytearray(pg.tobytes())
         if bit is None:
             bit = int(self.rng.integers(0, len(raw) * 8))
         raw[(bit // 8) % len(raw)] ^= 1 << (bit % 8)
         new = np.frombuffer(bytes(raw), dtype=pg.dtype).reshape(pg.shape)
         if isinstance(leaves[li], np.ndarray):
-            leaves[li][layer, pid] = new
+            leaves[li][pid] = new
         else:
-            leaves[li] = leaves[li].at[layer, pid].set(jnp.asarray(new))
+            leaves[li] = leaves[li].at[pid].set(jnp.asarray(new))
             engine.pools = jax.tree_util.tree_unflatten(treedef, leaves)
         self.stats["corruptions"] += 1
         self.log.append(("corrupt", layer, pid, bit))

@@ -122,12 +122,11 @@ class ReferencePagedKVEngine:
         self.prefill_chunk = (2 * page_size if prefill_chunk is None
                               else prefill_chunk)
         assert self.prefill_chunk % page_size == 0
-        lyr, k, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
         # compressed page pools (the LCP target-size + metadata regions):
         # the codec's pool pytree, held as host numpy leaves
         self.pools = jax.tree.map(          # np.array: writable host copies
-            np.array, self.codec.init_pools(lyr, n_pool_pages, k,
-                                            page_size, dh))
+            np.array, self.codec.init_pools(n_pool_pages, cfg.n_kv_heads,
+                                            page_size, cfg.head_dim))
         self.free: list[int] = list(range(n_pool_pages - 1, 0, -1))
         self.page_bytes = np.zeros(n_pool_pages, np.int64)
         # publish-time integrity checksums (faults.page_checksums),
@@ -262,7 +261,7 @@ class ReferencePagedKVEngine:
         pg = self.codec.compress_kv_pages(kk, vv)
         for pool, new in zip(jax.tree.leaves(self.pools),
                              jax.tree.leaves(pg)):
-            pool[li, pid] = np.asarray(new[0])
+            pool[pid] = np.asarray(new[0])
         # same byte-accounting function as the batched engine's device
         # path, so CAMP values and stats match bit-for-bit on prompt
         # pages (shared prefill dispatch) — decode-tail pages are only
@@ -445,7 +444,7 @@ class ReferencePagedKVEngine:
             for li in range(lyr):
                 pid = seq.pages[li][b]
                 kk, vv = self.codec.decompress_pages(jax.tree.map(
-                    lambda a: jnp.asarray(a[li, pid][None]), self.pools))
+                    lambda a: jnp.asarray(a[pid][None]), self.pools))
                 pf_k[li, 0, sl] = np.swapaxes(np.asarray(kk[0]), 0, 1)
                 pf_v[li, 0, sl] = np.swapaxes(np.asarray(vv[0]), 0, 1)
         seq.pf_k = jnp.asarray(pf_k)
@@ -570,7 +569,7 @@ class ReferencePagedKVEngine:
         parts_k, parts_v = [], []
         if pids:
             k_pages, v_pages = self.codec.decompress_pages(jax.tree.map(
-                lambda a: jnp.asarray(a[li, pids]), self.pools))
+                lambda a: jnp.asarray(a[pids]), self.pools))
             parts_k.append(jnp.swapaxes(k_pages, 1, 2).reshape(-1, kh, dh))
             parts_v.append(jnp.swapaxes(v_pages, 1, 2).reshape(-1, kh, dh))
         tl = seq.tail_len + 1

@@ -201,13 +201,13 @@ class TieredPageStore:
         self.codec_name = codec.name
         self.n_layers = n_layers
         self.page = page
-        # leaf layout from a 1-layer/1-page pool: static per-page packed
-        # sizes, so every intra-slot offset is arithmetic
-        proto = jax.tree.leaves(codec.init_pools(1, 1, kvh, page, dh))
+        # leaf layout from a 1-page pool: static per-page packed sizes,
+        # so every intra-slot offset is arithmetic
+        proto = jax.tree.leaves(codec.init_pools(1, kvh, page, dh))
         self._specs: list[_LeafSpec] = []
         off = 0
         for lf in proto:
-            shape = tuple(lf.shape[2:])
+            shape = tuple(lf.shape[1:])
             nb = int(np.prod(shape, dtype=np.int64)) * np.dtype(
                 lf.dtype).itemsize
             self._specs.append(_LeafSpec(off, nb, shape,

@@ -503,10 +503,10 @@ def test_adaptive_corrupt_tag_detected(small_model):
     li, pid = 0, eng.seqs[0].pages[0][0]
     pairs = [(li, pid)]
     assert F.verify_pages(eng, pairs).all()
-    tag_before = int(np.asarray(eng.pools.tag)[li, pid])
+    tag_before = int(np.asarray(eng.pools.tag)[pid])
     inj = F.FaultInjector(F.FaultSpec(), seed=0)
     inj.corrupt_page(eng, li, pid, bit=0)           # first leaf == tag
-    assert int(np.asarray(eng.pools.tag)[li, pid]) == tag_before ^ 1
+    assert int(np.asarray(eng.pools.tag)[pid]) == tag_before ^ 1
     assert not F.verify_pages(eng, pairs).all()
     assert not F.verify_seq(eng, 0)
 

@@ -7,7 +7,7 @@ import numpy as np
 
 from repro.checkpoint import store
 from repro.configs.registry import get_arch
-from repro.launch.elastic import abstract_mesh, reshard_plan, restore_elastic
+from repro.launch.elastic import reshard_plan, restore_elastic
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import get_model
 
@@ -38,8 +38,8 @@ def test_reshard_plan_flags_lost_sharding():
     big = jax.sharding.Mesh(
         np.array([dev]).reshape(1, 1), ("data", "model"))
     # fabricate an abstract 16-way mesh for the audit (no devices needed)
-    old = abstract_mesh((16, 16), ("data", "model"))
-    new = abstract_mesh((2, 2), ("data", "model"))
+    old = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
+    new = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
     plan = reshard_plan(shape_tree, old, new)
     assert plan, "shrinking the mesh must flag growth somewhere"
     growths = [v["replicated_growth"] for v in plan.values()]

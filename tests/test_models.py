@@ -15,7 +15,7 @@ import pytest
 
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import ARCHS, get_arch
-from repro.models import frontends
+from repro.models import frontends, transformer
 from repro.models.api import get_model
 
 SMOKE_SHAPE = ShapeConfig("smoke", seq_len=16, global_batch=2, kind="train")
@@ -114,6 +114,23 @@ def test_prefill_decode_matches_forward(smoke_setups, name):
     np.testing.assert_allclose(np.asarray(step_logits),
                                np.asarray(full_logits[:, -1]),
                                rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize(
+    "name", [n for n in ARCH_NAMES
+             if get_model(_smoke_cfg(n)).init.func is transformer.init_params])
+def test_jitted_init_matches_eager(name):
+    """The jitted transformer init gives the eager init's values, bit for
+    bit, leaf by leaf."""
+    cfg = _smoke_cfg(name)
+    key = jax.random.PRNGKey(0)
+    jitted = transformer.init_params(cfg, key)
+    with jax.disable_jit():
+        eager = transformer.init_params(cfg, key)
+    assert jax.tree.structure(jitted) == jax.tree.structure(eager)
+    for a, b in zip(jax.tree.leaves(jitted), jax.tree.leaves(eager)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_gemma3_local_global_pattern():

@@ -53,6 +53,18 @@ def test_serve_generation_runs():
     assert out["tok_per_s"] > 0
 
 
+def test_serve_scheduler_full_depth_finishes():
+    """Every published page takes one pool id per layer, so the pool that
+    ``generate`` builds must hold a long prompt at yi-6b's full depth
+    (reduced width) without CAMP-preempting it."""
+    from repro.launch.serve import generate
+    out = generate("yi-6b", smoke=True, n_layers=32, scheduler=True,
+                   prefix_cache=True, batch=2, prompt_len=256, gen=4)
+    assert out["n_layers"] == 32
+    assert [r["reason"] for r in out["per_request"].values()] \
+        == ["length", "length"]
+
+
 def test_mini_dryrun_subprocess():
     """Full dry-run machinery for the cheapest cell, in a fresh process."""
     env = dict(os.environ, PYTHONPATH=SRC)

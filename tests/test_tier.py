@@ -55,7 +55,7 @@ def _prompt(n, stride=7):
 def _entry_page_state(eng, eid):
     """One cache entry's device-resident bytes + publish metadata."""
     e = eng.prefix_cache.entries[eid]
-    leaves = [np.stack([np.asarray(lf[li, e.pages[li]])
+    leaves = [np.stack([np.asarray(lf[e.pages[li]])
                         for li in range(eng.cfg.n_layers)])
               for lf in jax.tree.leaves(eng.pools)]
     meta = [(int(eng.page_bytes[p]), int(eng.page_codec_id[p]),
@@ -349,7 +349,7 @@ def test_decode_pages_cached_across_turns(small_model):
     n_blocks = len(seq.pages[0])
     assert n_blocks == 4 and len(seq.chain) == 2
     decode_bits = [
-        [np.stack([np.asarray(lf[li, seq.pages[li][b]])
+        [np.stack([np.asarray(lf[seq.pages[li][b]])
                    for li in range(cfg.n_layers)])
          for lf in jax.tree.leaves(eng.pools)]
         for b in range(2, n_blocks)]
