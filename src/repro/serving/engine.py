@@ -896,14 +896,15 @@ class PagedKVEngine:
             self._pmax *= 2
             self._pt_dirty = True
         if self._pt_dirty or self._pt_dev is None:
-            lyr = self.cfg.n_layers
-            pt = np.zeros((lyr, self.max_batch, self._pmax), np.int32)
-            for s in self.seqs.values():
-                for li in range(lyr):
-                    ids = s.pages[li]
-                    pt[li, s.slot, :len(ids)] = ids
-            self._pt_dev = jnp.asarray(pt)
-            self._pt_dirty = False
+            with self.telemetry.tracer.span("engine.page_table"):
+                lyr = self.cfg.n_layers
+                pt = np.zeros((lyr, self.max_batch, self._pmax), np.int32)
+                for s in self.seqs.values():
+                    for li in range(lyr):
+                        ids = s.pages[li]
+                        pt[li, s.slot, :len(ids)] = ids
+                self._pt_dev = jnp.asarray(pt)
+                self._pt_dirty = False
         return self._pt_dev
 
     # -- request lifecycle -----------------------------------------------------
@@ -996,9 +997,10 @@ class PagedKVEngine:
         """Gather one page boundary's pool pages and hand them to the
         tier with their publish metadata (one device sync per demotion
         — demotion is off the admission/decode latency path)."""
-        leaves = [np.asarray(lf) for lf in jax.device_get(
-            jax.tree.leaves(_gather_entry_pages(
-                self.pools, jnp.asarray(pids, jnp.int32))))]
+        got = _gather_entry_pages(self.pools, jnp.asarray(pids, jnp.int32))
+        with self.telemetry.tracer.span("engine.sync"):
+            leaves = [np.asarray(lf)
+                      for lf in jax.device_get(jax.tree.leaves(got))]
         self.tier.demote(parent, toks, leaves,
                          [int(self.page_bytes[p]) for p in pids],
                          [int(self.page_codec_id[p]) for p in pids],
@@ -1178,6 +1180,11 @@ class PagedKVEngine:
         mixed dispatch's shapes static; requests arriving while a cohort
         is in flight wait for the next cohort.
         """
+        with self.telemetry.tracer.span("engine.begin_cohort"):
+            return self._begin_cohort(prompts)
+
+    def _begin_cohort(self, prompts: dict[int, list[int]]
+                      ) -> dict[int, int]:
         # a cohort whose live members all finished (the rest preempted)
         # may still be nominally in flight; clear it before validating
         self._maybe_drop_cohort()
@@ -1194,23 +1201,26 @@ class PagedKVEngine:
             return cached
         cfg, chunk, page = self.cfg, self.prefill_chunk, self.page
         lyr, kvh, dh = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        tracer = self.telemetry.tracer
         seqs, starts = [], []
         for sid, prompt in prompts.items():
             start, chain = 0, []
             if self.prefix_cache is not None:
-                start, chain = self.prefix_cache.lookup(prompt)
+                with tracer.span("prefix_cache.lookup"):
+                    start, chain = self.prefix_cache.lookup(prompt)
                 if self.integrity:
                     # warm-hit trust boundary: verify the chain's pool
                     # pages before mapping them; a corrupt entry
                     # truncates the hit (the request recomputes from
                     # there, never serving bad bytes)
-                    vstart, chain = F.verified_prefix(self, start, chain)
+                    with tracer.span("engine.verify"):
+                        vstart, chain = F.verified_prefix(self, start,
+                                                          chain)
                     if vstart != start:
                         self._m["integrity_failures"].inc()
-                        if self.telemetry.tracer.enabled:
-                            self.telemetry.tracer.event(
-                                sid, "hit_truncated", hit=start,
-                                verified=vstart)
+                        if tracer.enabled:
+                            tracer.event(sid, "hit_truncated", hit=start,
+                                         verified=vstart)
                         start = vstart
                 self.prefix_cache.pin(chain)
                 if self.tier is not None:
@@ -1340,13 +1350,14 @@ class PagedKVEngine:
             entries.extend((s, blk) for blk in range(co.pub[i], upto))
             co.pub[i] = max(co.pub[i], upto)
         if entries:
-            rows = jnp.asarray([co.row[s.sid] for s, _ in entries],
-                               jnp.int32)
-            blks = jnp.asarray([b for _, b in entries], jnp.int32)
-            kb, vb = _gather_prefill_blocks(co.kscr, co.vscr, rows, blks,
-                                            page=page)
-            self._publish(kb, vb, [s for s, _ in entries],
-                          blocks=[b for _, b in entries])
+            with self.telemetry.tracer.span("engine.publish"):
+                rows = jnp.asarray([co.row[s.sid] for s, _ in entries],
+                                   jnp.int32)
+                blks = jnp.asarray([b for _, b in entries], jnp.int32)
+                kb, vb = _gather_prefill_blocks(co.kscr, co.vscr, rows,
+                                                blks, page=page)
+                self._publish(kb, vb, [s for s, _ in entries],
+                              blocks=[b for _, b in entries])
         completed, tails = [], []
         for i, s in enumerate(co.seqs):
             stored = len(s.tokens) - 1
@@ -1417,9 +1428,9 @@ class PagedKVEngine:
             self.pools, k_blocks, v_blocks,
             jnp.asarray(pids, jnp.int32), codec=self.codec,
             use_fused=self.use_fused_fill, member_sizes=want_members)
-        # 1 sync per publish
-        nbytes, csums, tags, msizes = jax.device_get(
-            (nbytes, csums, tags, msizes))
+        with self.telemetry.tracer.span("engine.sync"):  # 1 per publish
+            nbytes, csums, tags, msizes = jax.device_get(
+                (nbytes, csums, tags, msizes))
         nbytes, csums = np.asarray(nbytes), np.asarray(csums)
         tags = np.asarray(tags)
         if msizes is not None:
@@ -1526,57 +1537,68 @@ class PagedKVEngine:
         # prefill half); larger pf_tokens would silently skip tokens
         n = 0 if co is None else max(0, min(pf_tokens, self.prefill_chunk,
                                             co.maxrel - co.roff))
-        if n > 0:
-            c = self.prefill_chunk
-            nrows, tmax = co.toks.shape
-            ptoks_h = np.zeros((nrows, c), np.int32)
-            offs_h = np.zeros(nrows, np.int32)
-            for i, s in enumerate(co.seqs):
-                r = co.row[s.sid]
-                # per-row absolute chunk start; clamped so the static-
-                # width scratch write stays in bounds for rows already
-                # past their stored length (their writes are garbage the
-                # grid never publishes or attends)
-                off = min(co.starts[i] + co.roff, tmax - c)
-                offs_h[r] = off
-                ptoks_h[r] = co.toks[r, off:off + c]
-            # budget-split chunk: tokens past the valid width are zero
-            # padding — their scratch writes land beyond off+n and are
-            # rewritten by the next chunk before any valid query (always
-            # at a position < its own write offset) can attend them
-            ptoks_h[:, n:] = 0
-            ptoks = jnp.asarray(ptoks_h)
-            offs_d = jnp.asarray(offs_h)
-        if sids:
-            page_cnt, last_tok, pos, tail_len, active = \
-                self._decode_inputs(sids)
+        tracer = self.telemetry.tracer
+        with tracer.span("engine.prepare"):
             if n > 0:
-                (nxt, self.tail_k, self.tail_v, co.kscr, co.vscr,
-                 co.kcan, co.vcan) = _mixed_step(
-                    self.params, self.pools, self.tail_k, self.tail_v,
-                    co.kscr, co.vscr, co.kcan, co.vcan,
-                    self._page_table(), page_cnt, last_tok, pos,
-                    tail_len, active, ptoks, offs_d, cfg=self.cfg,
-                    page=self.page, codec=self.codec,
-                    use_fused=self.use_fused)
-            else:
-                nxt, self.tail_k, self.tail_v = _decode_step(
-                    self.params, self.pools, self.tail_k, self.tail_v,
-                    self._page_table(), page_cnt, last_tok, pos, tail_len,
-                    active, cfg=self.cfg, codec=self.codec,
-                    use_fused=self.use_fused)
-            out = self._decode_post(sids, np.asarray(nxt))  # 1 sync / step
+                c = self.prefill_chunk
+                nrows, tmax = co.toks.shape
+                ptoks_h = np.zeros((nrows, c), np.int32)
+                offs_h = np.zeros(nrows, np.int32)
+                for i, s in enumerate(co.seqs):
+                    r = co.row[s.sid]
+                    # per-row absolute chunk start; clamped so the static-
+                    # width scratch write stays in bounds for rows already
+                    # past their stored length (their writes are garbage
+                    # the grid never publishes or attends)
+                    off = min(co.starts[i] + co.roff, tmax - c)
+                    offs_h[r] = off
+                    ptoks_h[r] = co.toks[r, off:off + c]
+                # budget-split chunk: tokens past the valid width are zero
+                # padding — their scratch writes land beyond off+n and are
+                # rewritten by the next chunk before any valid query
+                # (always at a position < its own write offset) can
+                # attend them
+                ptoks_h[:, n:] = 0
+                ptoks = jnp.asarray(ptoks_h)
+                offs_d = jnp.asarray(offs_h)
+            if sids:
+                page_cnt, last_tok, pos, tail_len, active = \
+                    self._decode_inputs(sids)
+                pt = self._page_table()
+        if sids:
+            with tracer.span("engine.dispatch"):
+                if n > 0:
+                    (nxt, self.tail_k, self.tail_v, co.kscr, co.vscr,
+                     co.kcan, co.vcan) = _mixed_step(
+                        self.params, self.pools, self.tail_k, self.tail_v,
+                        co.kscr, co.vscr, co.kcan, co.vcan, pt, page_cnt,
+                        last_tok, pos, tail_len, active, ptoks, offs_d,
+                        cfg=self.cfg, page=self.page, codec=self.codec,
+                        use_fused=self.use_fused)
+                else:
+                    nxt, self.tail_k, self.tail_v = _decode_step(
+                        self.params, self.pools, self.tail_k, self.tail_v,
+                        pt, page_cnt, last_tok, pos, tail_len, active,
+                        cfg=self.cfg, codec=self.codec,
+                        use_fused=self.use_fused)
+            with tracer.span("engine.sync"):          # 1 sync / step
+                nxt = np.asarray(nxt)
+            out = self._decode_post(sids, nxt)
         else:
             out = {}
             if n > 0:
-                co.kscr, co.vscr, co.kcan, co.vcan = _prefill_chunk(
-                    self.params, ptoks, co.kscr, co.vscr, co.kcan,
-                    co.vcan, offs_d, cfg=self.cfg, page=self.page,
-                    codec=self.codec)
+                with tracer.span("engine.dispatch"):
+                    co.kscr, co.vscr, co.kcan, co.vcan = _prefill_chunk(
+                        self.params, ptoks, co.kscr, co.vscr, co.kcan,
+                        co.vcan, offs_d, cfg=self.cfg, page=self.page,
+                        codec=self.codec)
         # decode tail publishes land first (inside _decode_post), then the
         # chunk's completed prefill pages — the reference oracle replays
         # the same iteration order
-        completed = self._advance_cohort(n) if n > 0 else []
+        completed = []
+        if n > 0:
+            with tracer.span("engine.cohort_advance"):
+                completed = self._advance_cohort(n)
         # a decode-side publish may have preempted the cohort's last live
         # member this very step; don't leave a dead cohort in flight
         self._maybe_drop_cohort()
@@ -1618,9 +1640,11 @@ class PagedKVEngine:
                 filled.append(s)
                 s.tail_len = 0
         if filled:
-            slots = jnp.asarray([s.slot for s in filled], jnp.int32)
-            kb, vb = _gather_tail_blocks(self.tail_k, self.tail_v, slots)
-            self._publish(kb, vb, filled)
+            with self.telemetry.tracer.span("engine.publish"):
+                slots = jnp.asarray([s.slot for s in filled], jnp.int32)
+                kb, vb = _gather_tail_blocks(self.tail_k, self.tail_v,
+                                             slots)
+                self._publish(kb, vb, filled)
         return out
 
     def decode_one(self, sid: int) -> int:

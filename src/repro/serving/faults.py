@@ -142,7 +142,9 @@ def pair_checksums(engine, pairs: list[tuple[int, int]]) -> np.ndarray:
     pp = np.zeros(cap, np.int32)          # 0: the padding page
     pp[:n] = pa
     out = _gather_checksums(engine.pools, jnp.asarray(pp))
-    return np.asarray(out)[:n]
+    with engine.telemetry.tracer.span("engine.sync"):
+        out = np.asarray(out)
+    return out[:n]
 
 
 def verify_pages(engine, pairs: list[tuple[int, int]]) -> np.ndarray:

@@ -179,21 +179,16 @@ class ContinuousScheduler:
         self._h_ttft = hist("serve_ttft_seconds",
                             "submit -> first token (monotonic clock)",
                             codec=cn)
-        self._h_ttft_it = hist("serve_ttft_iterations",
-                               "submit -> first token, in scheduler "
-                               "iterations (deterministic)", codec=cn)
         self._h_itl = hist("serve_intertoken_seconds",
                            "gap between consecutive decode tokens",
                            codec=cn)
         self._h_lat = hist("serve_request_latency_seconds",
                            "submit -> finish for requests that produced "
                            "output", codec=cn)
-        self._h_lat_it = hist("serve_request_latency_iterations",
-                              "submit -> finish, in iterations",
-                              codec=cn)
         self._h_disp = hist("sched_dispatch_seconds",
-                            "host wall time around the engine dispatch "
-                            "(includes the device sync)", codec=cn)
+                            "host wall time around the whole engine "
+                            "dispatch: the step, its token sync, "
+                            "publishes and the cohort advance", codec=cn)
 
     @property
     def stats(self) -> dict:
@@ -275,7 +270,6 @@ class ContinuousScheduler:
                     reason=str(reason)).inc()
         if tr.out_tokens:                 # latency only for served work
             self._h_lat.observe(now - tr.submitted_t)
-            self._h_lat_it.observe(it - tr.submitted_iter)
         self.trace.finish(tr.req.rid, reason)
 
     @property
@@ -296,8 +290,14 @@ class ContinuousScheduler:
 
         Returns an event dict: ``admitted`` rids, ``decoded`` {rid: tok},
         ``prefilled`` token count, ``completed_prefills`` rids,
-        ``retired`` [(rid, reason)], and ``idle``.
+        ``retired`` [(rid, reason)], and ``idle``.  The iteration is
+        the host span ``sched.step``; the admission, the engine's phases
+        and the retirement are spans inside it.
         """
+        with self.trace.span("sched.step", iteration=self.iteration) as sp:
+            return self._step(sp)
+
+    def _step(self, sp) -> dict:
         it = self.iteration
         faults = getattr(self.engine, "faults", None)
         if faults is not None:
@@ -325,6 +325,7 @@ class ContinuousScheduler:
         admitted = self._admit()
         decode_rids = list(self._running)
         n_pf = self._plan_prefill_tokens(len(decode_rids))
+        sp.set_metadata(decode_rows=len(decode_rids), prefill_tokens=n_pf)
         self._g_running.set(len(self._running))
         self._g_waiting.set(len(self.waiting))
         if not decode_rids and n_pf == 0:
@@ -338,10 +339,8 @@ class ContinuousScheduler:
                     "prefilled": 0, "completed_prefills": [],
                     "retired": expired, "idle": True}
 
-        # host wall time around the whole dispatch: for the batched
-        # engine the decode post-step materializes the step's tokens on
-        # host (the block_until_ready of this design), so this span is
-        # submit-to-sync, not just call overhead
+        # host wall time around the whole dispatch: the engine's step,
+        # its token sync, the publishes and the cohort advance
         t_disp = self.clock.now()
         out, completed = self._dispatch(decode_rids, n_pf)
         dispatch_s = self.clock.now() - t_disp
@@ -356,7 +355,6 @@ class ContinuousScheduler:
                 tr.first_token_iter = it
                 tr.first_token_t = now
                 self._h_ttft.observe(now - tr.submitted_t)
-                self._h_ttft_it.observe(it - tr.submitted_iter)
                 if self.trace.enabled:
                     self.trace.event(rid, "first_token", token=tok)
             else:
@@ -386,7 +384,8 @@ class ContinuousScheduler:
                 self.trace.phase(rid, "decode")
         self._prefill = [r for r in self._prefill if r not in completed]
 
-        retired = self._retire(out, now)
+        with self.trace.span("sched.retire"):
+            retired = self._retire(out, now)
         self._check_stall(it, True)       # a dispatch ran: progress
         self.iteration += 1
         self._m["iterations"].inc()
@@ -523,42 +522,43 @@ class ContinuousScheduler:
         if self.ladder is not None \
                 and self.ladder.level >= self.ladder.n_levels:
             return []                     # overload: admissions paused
-        free = (len(self.engine._free_slots) if self._batched
-                else self._ref_free_slots())
-        cohort: list[Request] = []
-        while self.waiting and len(cohort) < free:
-            cohort.append(self.waiting.popleft())
-        if not cohort:
-            return []
-        prompts = {r.rid: r.prompt for r in cohort}
-        if self._batched:
-            starts = self.engine.begin_cohort(prompts)
-        else:
-            starts = {rid: self.engine.begin_request(rid, prompt)
-                      for rid, prompt in prompts.items()}
-        for r in cohort:
-            tr = self.tracks[r.rid]
-            tr.admitted_iter = self.iteration
-            tr.pf_start = starts[r.rid]
-            tr.pf_pos = starts[r.rid]
-            self._m["prefix_cached_tokens"].inc(starts[r.rid])
-            if self.trace.enabled:
-                self.trace.event(r.rid, "admitted",
-                                 cached_tokens=starts[r.rid])
-                if starts[r.rid] > 0:
-                    self.trace.event(r.rid, "cache_hit",
-                                     tokens=starts[r.rid])
-            if starts[r.rid] >= len(r.prompt) - 1:
-                tr.state = "running"          # full hit: no prefill phase
-                tr.prefill_done_iter = self.iteration
-                self._running.append(r.rid)
-                self.trace.phase(r.rid, "decode")
+        with self.trace.span("sched.admit"):
+            free = (len(self.engine._free_slots) if self._batched
+                    else self._ref_free_slots())
+            cohort: list[Request] = []
+            while self.waiting and len(cohort) < free:
+                cohort.append(self.waiting.popleft())
+            if not cohort:
+                return []
+            prompts = {r.rid: r.prompt for r in cohort}
+            if self._batched:
+                starts = self.engine.begin_cohort(prompts)
             else:
-                tr.state = "prefill"
-                self._prefill.append(r.rid)
-                self.trace.phase(r.rid, "prefill")
-        self._cohort_pos = 0
-        return [r.rid for r in cohort]
+                starts = {rid: self.engine.begin_request(rid, prompt)
+                          for rid, prompt in prompts.items()}
+            for r in cohort:
+                tr = self.tracks[r.rid]
+                tr.admitted_iter = self.iteration
+                tr.pf_start = starts[r.rid]
+                tr.pf_pos = starts[r.rid]
+                self._m["prefix_cached_tokens"].inc(starts[r.rid])
+                if self.trace.enabled:
+                    self.trace.event(r.rid, "admitted",
+                                     cached_tokens=starts[r.rid])
+                    if starts[r.rid] > 0:
+                        self.trace.event(r.rid, "cache_hit",
+                                         tokens=starts[r.rid])
+                if starts[r.rid] >= len(r.prompt) - 1:
+                    tr.state = "running"      # full hit: no prefill phase
+                    tr.prefill_done_iter = self.iteration
+                    self._running.append(r.rid)
+                    self.trace.phase(r.rid, "decode")
+                else:
+                    tr.state = "prefill"
+                    self._prefill.append(r.rid)
+                    self.trace.phase(r.rid, "prefill")
+            self._cohort_pos = 0
+            return [r.rid for r in cohort]
 
     def _ref_free_slots(self) -> int:
         """Oracle twin of the batched engine's free-slot count."""

@@ -12,12 +12,20 @@ loads directly: one *thread* per request id showing its phase slices,
 one counter track per timeline series.  See serving/README.md
 ("Observability") for the schema and a worked example.
 
+Host phases of the serving loop (``sched.step`` and what runs inside
+it) are recorded through :meth:`Tracer.span`: always as a
+``jax.profiler.TraceAnnotation``, so a profiler trace holds them beside
+the device's events and each device idle gap can be set against the
+host phase it fell in; with tracing on, also as slices on the
+scheduler track (tid 0) of the Chrome export.
+
 Cost model: tracing is opt-in (``Telemetry(trace=True)``).  Every
 recording method starts with an ``enabled`` check and hot call sites in
 the scheduler guard on ``tracer.enabled`` before building event
-arguments, so the disabled path is a single attribute test — the bench
-gates traced goodput at >= 0.97x untraced
-(``benchmarks/check_serve_regression.py``).
+arguments, so the disabled path is a single attribute test.  A span
+with no profiler running costs its ``TraceAnnotation`` enter and exit,
+about a microsecond; PERF.md gives the cost per step measured on the
+chip, with the profiler off and on.
 
 Timestamps come from the shared monotonic :class:`~.telemetry.Clock`
 in microseconds relative to the tracer's start — never wall-clock, so
@@ -30,6 +38,8 @@ times.
 from __future__ import annotations
 
 import json
+
+from jax.profiler import TraceAnnotation
 
 # Phases a request moves through; each becomes an "X" slice on the
 # request's trace thread.
@@ -53,6 +63,8 @@ class Tracer:
         self._open: dict = {}
         # (t_us, iteration, {series: value}) — counter timeline
         self.counters: list[tuple] = []
+        # (t0_us, t1_us, name, args|None) — closed host-phase spans
+        self.spans: list[tuple] = []
 
     # -- recording -------------------------------------------------------------
 
@@ -90,6 +102,21 @@ class Tracer:
         if not self.enabled:
             return
         self.counters.append((self.clock.us(), it, series))
+
+    def span(self, name: str, **args):
+        """Context manager around one host phase of the serving loop.
+
+        Always a ``jax.profiler.TraceAnnotation`` named ``name``
+        (prefixed by its layer: ``sched.``, ``engine.``,
+        ``prefix_cache.``), so a running profiler records it beside the
+        device's events; enabled, the tracer also keeps it as a slice on
+        the scheduler track.  ``args`` are ints the caller already
+        holds; ``set_metadata(**more)`` on the entered span adds others
+        before it closes.  Never call it inside a jitted function.
+        """
+        if not self.enabled:
+            return TraceAnnotation(name, **args)
+        return _Span(self, name, args)
 
     # -- queries ---------------------------------------------------------------
 
@@ -148,6 +175,10 @@ class Tracer:
             for k, v in series.items():
                 evs.append({"ph": "C", "pid": pid, "tid": 0, "name": k,
                             "ts": t, "args": {k: v, "iteration": it}})
+        for t0, t1, name, args in self.spans:
+            evs.append({"ph": "X", "pid": pid, "tid": 0, "name": name,
+                        "cat": "span", "ts": t0, "dur": max(t1 - t0, 0),
+                        "args": args or {}})
         return {"traceEvents": evs, "displayTimeUnit": "ms"}
 
     def write_chrome_trace(self, path) -> None:
@@ -161,7 +192,8 @@ class Tracer:
                 "events": [list(e) for e in self.events],
                 "slices": [list(s) for s in self.slices],
                 "open": {str(r): list(p) for r, p in self._open.items()},
-                "counters": [[t, i, dict(s)] for t, i, s in self.counters]}
+                "counters": [[t, i, dict(s)] for t, i, s in self.counters],
+                "spans": [list(s) for s in self.spans]}
 
     def load_state(self, s: dict) -> None:
         self.enabled = s["enabled"]
@@ -169,6 +201,32 @@ class Tracer:
         self.slices = [tuple(e) for e in s["slices"]]
         self._open = {_unkey(r): tuple(p) for r, p in s["open"].items()}
         self.counters = [(t, i, s_) for t, i, s_ in s["counters"]]
+        self.spans = [tuple(x) for x in s.get("spans", [])]
+
+
+class _Span:
+    """An enabled tracer's span: the profiler annotation plus a slice
+    on the tracer's own clock, appended when the span closes."""
+
+    __slots__ = ("tracer", "name", "args", "ann", "t0")
+
+    def __init__(self, tracer: Tracer, name: str, args: dict):
+        self.tracer, self.name, self.args = tracer, name, args
+        self.ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = self.tracer.clock.us()
+        return self
+
+    def set_metadata(self, **args) -> None:
+        self.args.update(args)
+        self.ann.set_metadata(**args)
+
+    def __exit__(self, *exc):
+        self.tracer.spans.append((self.t0, self.tracer.clock.us(),
+                                  self.name, dict(self.args) or None))
+        return self.ann.__exit__(*exc)
 
 
 def _tid(rid) -> int:

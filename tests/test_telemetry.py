@@ -260,6 +260,26 @@ def test_disabled_tracer_records_nothing():
     assert [e["ph"] for e in t["traceEvents"]] == ["M", "M"]
 
 
+def test_tracer_span_slices_only_when_enabled():
+    on, off = Tracer(Clock(), enabled=True), Tracer(Clock(), enabled=False)
+    for tr in (on, off):
+        with tr.span("sched.step", iteration=3) as sp:
+            sp.set_metadata(decode_rows=2)
+            with tr.span("engine.sync"):
+                pass
+    assert not off.spans
+    assert [s[2] for s in on.spans] == ["engine.sync", "sched.step"]
+    (i0, i1, _, iargs), (o0, o1, _, oargs) = on.spans
+    assert o0 <= i0 <= i1 <= o1
+    assert iargs is None and oargs == {"iteration": 3, "decode_rows": 2}
+    xs = [e for e in on.to_chrome_trace()["traceEvents"] if e["ph"] == "X"]
+    assert [(e["tid"], e["cat"], e["name"]) for e in xs] == \
+        [(0, "span", "engine.sync"), (0, "span", "sched.step")]
+    again = Tracer(Clock())
+    again.load_state(json.loads(json.dumps(on.state())))
+    assert again.spans == on.spans
+
+
 def test_tracer_phases_and_finish():
     tr = Tracer(Clock(), enabled=True)
     tr.event(7, "submit")
@@ -371,7 +391,12 @@ def test_chrome_trace_schema(traced_run):
     assert {e["ph"] for e in evs} == {"M", "X", "i", "C"}
     for e in evs:
         assert {"ph", "pid", "tid", "name"} <= set(e)
-        if e["ph"] == "X":
+        if e["ph"] == "X" and e["cat"] == "span":
+            assert e["tid"] == 0
+            assert e["name"].startswith(("sched.", "engine.",
+                                         "prefix_cache."))
+            assert e["dur"] >= 0 and e["ts"] >= 0
+        elif e["ph"] == "X":
             assert e["cat"] == "request"
             assert e["name"] in PHASES
             assert e["dur"] >= 0 and e["ts"] >= 0
@@ -386,6 +411,12 @@ def test_chrome_trace_schema(traced_run):
     assert {"decode_tokens", "prefill_tokens", "token_budget",
             "pool_used_pages", "free_list_depth",
             "dispatch_ms"} <= counter_names
+    # and the host phases of each iteration, on the scheduler track
+    span_names = {e["name"] for e in evs if e.get("cat") == "span"}
+    assert {"sched.step", "sched.admit", "sched.retire",
+            "engine.begin_cohort", "engine.prepare", "engine.dispatch",
+            "engine.sync", "engine.publish",
+            "engine.cohort_advance"} <= span_names
 
 
 def test_two_seeded_runs_trace_identically(small_model):
@@ -424,6 +455,7 @@ def test_telemetry_snapshot_restore_roundtrip(small_model, tmp_path):
     assert tel2.tracer.enabled
     assert tel2.tracer.events == tel.tracer.events
     assert tel2.tracer.slices == tel.tracer.slices
+    assert tel2.tracer.spans == tel.tracer.spans
     assert sched2.stats == sched.stats
     assert eng2.stats == eng.stats
 
@@ -435,3 +467,87 @@ def test_telemetry_snapshot_restore_roundtrip(small_model, tmp_path):
     h = tel2.registry.histogram("serve_ttft_seconds",
                                 codec=eng2.codec.name)
     assert h.count == 2
+
+
+# ------------------------------------------- host spans on the profiler
+
+
+def _profiled_host_spans(trace_dir):
+    """(name, start_ns, end_ns, stats) of the serving loop's host spans
+    in the newest profiler trace under ``trace_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("sched.", "engine.",
+                                      "prefix_cache.")):
+                    out.append((e.name, e.start_ns, e.end_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda s: (s[1], -s[2]))
+
+
+def _inside(spans, parent, name):
+    return [s for s in spans if s[0] == name and s is not parent
+            and parent[1] <= s[1] and s[2] <= parent[2]]
+
+
+def test_step_spans_nest_on_the_profiler_clock(small_model, tmp_path):
+    import jax
+
+    from repro.serving.engine import PagedKVEngine
+    from repro.serving.prefix_cache import PrefixCache
+    from repro.serving.scheduler import ContinuousScheduler
+
+    cfg, params = small_model
+    eng = PagedKVEngine(cfg, params, page_size=PAGE, n_pool_pages=96,
+                        max_batch=2,
+                        prefix_cache=PrefixCache.for_model(cfg, PAGE))
+    sched = ContinuousScheduler(eng, token_budget=24)
+    head = [1 + j for j in range(2 * PAGE)]
+    sched.submit(0, head + [50], max_new_tokens=PAGE + 2)
+    events = []
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for it in range(PAGE + 2):
+            if it == 3:      # its whole stored prompt is in the cache
+                sched.submit(1, head + [60], max_new_tokens=2)
+            events.append(sched.step())
+    finally:
+        jax.profiler.stop_trace()
+    spans = _profiled_host_spans(str(tmp_path))
+    steps = {s[3]["iteration"]: s for s in spans if s[0] == "sched.step"}
+    assert sorted(steps) == [ev["iteration"] for ev in events]
+    filled = [ev["iteration"] for ev in events
+              if ev["decoded"] and 0 in ev["decoded"]][PAGE - 1]
+    for ev in events:
+        st = steps[ev["iteration"]]
+        assert len(_inside(spans, st, "engine.dispatch")) == 1
+        if not ev["decoded"]:
+            continue
+        assert st[3]["decode_rows"] == len(ev["decoded"])
+        pubs = _inside(spans, st, "engine.publish")
+        for p in pubs:
+            assert len(_inside(spans, p, "engine.sync")) == 1
+        # the token read follows the dispatch; a publish adds its own
+        # read, a prefix hit's admission its checksum read
+        dispatch = _inside(spans, st, "engine.dispatch")[0]
+        syncs = _inside(spans, st, "engine.sync")
+        token = [s for s in syncs if s[1] >= dispatch[2]][0]
+        assert not any(p[1] <= token[1] <= p[2] for p in pubs)
+        verifies = _inside(spans, st, "engine.verify")
+        assert len(syncs) == 1 + len(pubs) + len(verifies)
+        assert bool(pubs) == (ev["iteration"] == filled)
+    admit = _inside(spans, steps[3], "sched.admit")
+    assert events[3]["admitted"] == [1] and len(admit) == 1
+    for name in ("engine.begin_cohort", "prefix_cache.lookup",
+                 "engine.verify", "engine.sync"):
+        assert len(_inside(spans, admit[0], name)) == 1, name
+    verify = _inside(spans, admit[0], "engine.verify")[0]
+    assert len(_inside(spans, verify, "engine.sync")) == 1
