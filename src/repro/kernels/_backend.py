@@ -17,6 +17,12 @@ def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def resolve_interpret(interpret: bool | None) -> bool:
-    """Kernel-entry helper: explicit argument wins, else backend default."""
-    return default_interpret() if interpret is None else bool(interpret)
+def resolve_interpret(interpret):
+    """Kernel-entry helper: explicit argument wins, else backend default.
+
+    Besides a bool, the argument may be ``pltpu.InterpretParams``: the
+    TPU interpreter, which models DMAs and semaphores, fills fresh VMEM
+    with NaN and raises on an out-of-bounds read."""
+    if interpret is None:
+        return default_interpret()
+    return bool(interpret) if isinstance(interpret, int) else interpret

@@ -61,7 +61,8 @@ prompt token's K/V exactly once into the tail (this fixed the historical
     across the whole batch.
   * Page tables are padded to a static ``PMAX`` (doubled on demand, which
     retraces at most a handful of times) so shapes stay static across
-    steps; inactive batch slots ride along masked.
+    steps; inactive batch slots ride along masked (the fused kernel
+    walks only each row's valid pages, so they cost it only a tail).
   * Attention over [compressed pages + uncompressed tail] selects its
     implementation by backend and codec: on TPU a codec that ships a
     fused kernel (BDI: the fused-dequant Pallas kernel,
@@ -669,12 +670,17 @@ class PagedKVEngine:
                   "bytes_compressed", "preemptions",
                   "prefix_pages_evicted", "shed_inserts",
                   "integrity_failures")
+    # what the batched decode-attention walk covers, per dispatch times
+    # layers: the active rows' pages, and the padded [max_batch, PMAX]
+    # table a dense walk would visit.  The host-looped oracle has no
+    # batched walk, so it carries none of these.
+    _WALK_KEYS = ("attn_pages_read", "attn_pages_padded")
 
     def _init_metrics(self) -> None:
         reg = self.telemetry.registry
         cn = self.codec.name
         self._m = {k: reg.counter(f"engine_{k}_total", codec=cn)
-                   for k in self._STAT_KEYS}
+                   for k in self._STAT_KEYS + self._WALK_KEYS}
         self._g_pool_used = reg.gauge(
             "engine_pool_used_pages", "mapped pool pages (id 0 excluded)")
         self._g_free = reg.gauge(
@@ -1562,9 +1568,9 @@ class PagedKVEngine:
                 ptoks = jnp.asarray(ptoks_h)
                 offs_d = jnp.asarray(offs_h)
             if sids:
+                pt = self._page_table()
                 page_cnt, last_tok, pos, tail_len, active = \
                     self._decode_inputs(sids)
-                pt = self._page_table()
         if sids:
             with tracer.span("engine.dispatch"):
                 if n > 0:
@@ -1605,7 +1611,9 @@ class PagedKVEngine:
         return out, completed
 
     def _decode_inputs(self, sids: list[int]):
-        """Pack the padded per-slot decode state for a dispatch."""
+        """Pack the padded per-slot decode state for a dispatch, and count
+        the pages its attention walks (``_WALK_KEYS``) against the
+        padded table of the current ``PMAX``."""
         sb = self.max_batch
         active = np.zeros(sb, bool)
         last_tok = np.zeros(sb, np.int32)
@@ -1619,6 +1627,9 @@ class PagedKVEngine:
             pos[s.slot] = len(s.tokens) - 1
             tail_len[s.slot] = s.tail_len
             page_cnt[s.slot] = len(s.pages[0])
+        lyr = self.cfg.n_layers
+        self._m["attn_pages_read"].inc(lyr * int(page_cnt.sum()))
+        self._m["attn_pages_padded"].inc(lyr * sb * self._pmax)
         return (jnp.asarray(page_cnt), jnp.asarray(last_tok),
                 jnp.asarray(pos), jnp.asarray(tail_len),
                 jnp.asarray(active))

@@ -157,6 +157,7 @@ class ReferencePagedKVEngine:
     # construction — identical attribute contracts (codec, telemetry,
     # free, pool_used_pages), identical metric series
     _STAT_KEYS = _E.PagedKVEngine._STAT_KEYS
+    _WALK_KEYS = ()                # one sequence per call: no batched walk
     _init_metrics = _E.PagedKVEngine._init_metrics
     _publish_metrics = _E.PagedKVEngine._publish_metrics
     stats = _E.PagedKVEngine.stats

@@ -2,14 +2,24 @@
 
 import pytest
 
+from repro.serving.engine import PagedKVEngine
+
+
+def oracle_view(stats: dict) -> dict:
+    """The counters the host-looped oracle mirrors: all but the batched
+    engine's decode-attention walk (``PagedKVEngine._WALK_KEYS``)."""
+    return {k: v for k, v in stats.items()
+            if k not in PagedKVEngine._WALK_KEYS}
+
 
 def assert_engine_stats_match(a, b, codec):
     """Engine-vs-oracle stats equality, codec-size-stability aware.
 
-    Every counter must match exactly.  ``bytes_compressed`` is the one
-    exception: it sums ``page_nbytes`` over published pages, and for
-    codecs with ``ulp_stable_sizes = False`` (fpc, adaptive) the size
-    function reads exact bit patterns — decode-tail KV at layers >= 1 is
+    Every counter the oracle mirrors must match exactly.
+    ``bytes_compressed`` is the one exception: it sums ``page_nbytes``
+    over published pages, and for codecs with ``ulp_stable_sizes =
+    False`` (fpc, adaptive) the size function reads exact bit patterns
+    — decode-tail KV at layers >= 1 is
     token-pinned but not bit-pinned across the batched engine and the
     op-by-op oracle, so a word can flip between the bf16-exact and
     full-exception classes.  Allow a few bytes of class-flip skew per
@@ -17,6 +27,7 @@ def assert_engine_stats_match(a, b, codec):
     twice, a dedup reversal missed) is hundreds of bytes and still
     trips the tolerance.
     """
+    a, b = oracle_view(a), oracle_view(b)
     if codec.ulp_stable_sizes:
         assert a == b
         return
@@ -31,6 +42,11 @@ def assert_engine_stats_match(a, b, codec):
 @pytest.fixture
 def assert_stats():
     return assert_engine_stats_match
+
+
+@pytest.fixture
+def oracle_stats():
+    return oracle_view
 
 
 def pytest_configure(config):

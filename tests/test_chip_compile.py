@@ -1,5 +1,6 @@
 """Compiles of the serving path's kernels and decode step for a described
-TPU v5e, at yi-6b's published widths.
+TPU v5e, at yi-6b's published widths (and the attention kernel at
+Qwen2.5-14B's).
 
 Nothing runs: the TPU compiler, which is installed with jax, compiles for
 a chip that is described and not attached.  It refuses what interpret
@@ -68,16 +69,27 @@ def _on_chip(tree, spec):
     return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
 
 
-def test_paged_attention_tail_compiles(spec):
-    page = 16
+def _compile_paged_attention_tail(spec, b, kvh, g, pmax, page=16):
     pages = _on_chip(jax.eval_shape(
-        lambda: codecs.get("bdi").init_pools(256, KVH, page, D)), spec)
+        lambda: codecs.get("bdi").init_pools(256, kvh, page, D)), spec)
     fn = jax.jit(lambda *a: paged_attention_tail(*a, interpret=False))
-    fn.lower(spec((B, KVH, G, D), jnp.float32), pages,
-             spec((B, PMAX), jnp.int32), spec((B,), jnp.int32),
-             spec((B, KVH, page, D), jnp.float32),
-             spec((B, KVH, page, D), jnp.float32),
-             spec((B,), jnp.int32)).compile()
+    fn.lower(spec((b, kvh, g, D), jnp.float32), pages,
+             spec((b, pmax), jnp.int32), spec((b,), jnp.int32),
+             spec((b, kvh, page, D), jnp.float32),
+             spec((b, kvh, page, D), jnp.float32),
+             spec((b,), jnp.int32)).compile()
+
+
+def test_paged_attention_tail_compiles(spec):
+    _compile_paged_attention_tail(spec, B, KVH, G, PMAX)
+
+
+def test_paged_attention_tail_compiles_at_qwen_widths(spec):
+    """Qwen2.5-14B's heads: GQA group 5, which is no multiple of the 8
+    sublanes, 8 KV heads, and the long-decode cell's 512-page table."""
+    qwen = get_arch("qwen2.5-14b")
+    _compile_paged_attention_tail(spec, 4, qwen.n_kv_heads,
+                                  qwen.n_heads // qwen.n_kv_heads, 512)
 
 
 def test_bdi_compress_kv_compiles(spec):

@@ -11,9 +11,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.configs.registry import get_arch
-from repro.kernels import ops, ref
+from repro.kernels import ref
+from repro.kernels.paged_attention import paged_attention_tail
 from repro.models.api import get_model
 from repro.serving.engine import PagedKVEngine
 from repro.serving.reference import ReferencePagedKVEngine
@@ -140,8 +142,8 @@ def test_release_recycles_slot_and_pages(small_model):
 
 
 @pytest.mark.bf16_tie_sensitive
-def test_chunked_prefill_batched_admission_matches_reference(small_model,
-                                                             assert_stats):
+def test_chunked_prefill_batched_admission_matches_reference(
+        small_model, assert_stats, oracle_stats):
     """One chunked-batch prefill pass == sequential oracle prefill.
 
     Ragged prompts around the chunk grid (chunk = 2 * PAGE = 16): shorter
@@ -157,7 +159,7 @@ def test_chunked_prefill_batched_admission_matches_reference(small_model,
     be.add_requests(prompts)
     for sid in prompts:
         assert re_.seqs[sid].tail_len == be.seqs[sid].tail_len, sid
-    assert re_.stats == be.stats        # prefill-side page accounting
+    assert oracle_stats(re_.stats) == oracle_stats(be.stats)  # prefill
     for step in range(12):
         out = be.decode_batch()
         for sid in prompts:
@@ -166,7 +168,7 @@ def test_chunked_prefill_batched_admission_matches_reference(small_model,
     assert re_.pool_used_pages() == be.pool_used_pages()
 
 
-def test_prefill_camp_preemption_mid_prefill(small_model):
+def test_prefill_camp_preemption_mid_prefill(small_model, oracle_stats):
     """A prompt whose prefill exhausts the pool evicts the done victim.
 
     Seq 0 (done, CAMP value -1) holds 10 pages; seq 1's prefill demands 10
@@ -186,7 +188,7 @@ def test_prefill_camp_preemption_mid_prefill(small_model):
         eng.add_request(1, long_b)
         assert eng.seqs[0].preempted, "prefill never forced the preemption"
         assert not eng.seqs[1].preempted
-    assert re_.stats == be.stats
+    assert oracle_stats(re_.stats) == oracle_stats(be.stats)
     assert re_.stats["preemptions"] == 1
     assert re_.stats["pages_evicted"] == 10
     for step in range(6):
@@ -258,25 +260,72 @@ def test_gqa_forward_external_kv_projects_once(monkeypatch):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_paged_attention_tail_matches_ref():
-    """Tail-fused kernel == dense dequant oracle, incl. zero-page seqs."""
-    key = jax.random.PRNGKey(7)
-    bsz, kvh, g, d, page, pmax, pool = 3, 2, 4, 16, 8, 4, 12
-    ks = jax.random.split(key, 6)
+# Kernel cases: (kvh, g, d, page, pmax, rows), each row (page tokens, tail
+# slots).  yi-6b's and Qwen2.5-14B's head layouts at full head width; a
+# PMAX that is not a multiple of the 128-token block; rows with no pages
+# (an inactive slot holds one tail slot, as the engine passes it), at
+# full PMAX, and ending mid-page and mid-block.
+TAIL_CASES = {
+    "small": (2, 4, 16, 8, 4, [(2 * 8, 3), (0, 1), (4 * 8, 8)]),
+    "yi": (4, 8, 128, 16, 20, [(20 * 16, 16), (0, 3), (0, 1),
+                               (9 * 16 + 5, 2)]),
+    "qwen": (8, 5, 128, 16, 12, [(12 * 16, 5), (0, 1), (8 * 16, 16),
+                                 (3 * 16 + 1, 1)]),
+}
+
+
+@pytest.mark.parametrize("case", list(TAIL_CASES))
+def test_paged_attention_tail_matches_ref(case):
+    """Tail-fused kernel == dense dequant oracle, on the TPU interpreter.
+
+    Table entries past each row's pages name pool pages that do not
+    exist: the interpreter raises on an out-of-bounds read, so passing
+    proves the kernel never copies a page past a row's length.  The
+    oracle, which reads the whole padded table, gets scrambled in-pool
+    pages there instead, which its mask drops."""
+    kvh, g, d, page, pmax, rows = TAIL_CASES[case]
+    bsz, pool = len(rows), 2 + sum(-(-n // page) for n, _ in rows)
+    ks = jax.random.split(jax.random.PRNGKey(7), 6)
     q = jax.random.normal(ks[0], (bsz, kvh, g, d))
     k = jax.random.normal(ks[1], (pool, kvh, page, d))
     v = jax.random.normal(ks[2], (pool, kvh, page, d))
     pages = ref.compress_kv_pages(k, v)
-    pt = jax.random.randint(ks[3], (bsz, pmax), 0, pool)
-    # seq 1 has zero published pages (tail-only attention)
-    lengths = jnp.asarray([2 * page, 0, 4 * page], jnp.int32)
+    scram = np.array(jax.random.randint(ks[3], (bsz, pmax), 1, pool))
+    pt, nxt = np.full((bsz, pmax), pool + 1000, np.int32), 1
+    for b, (n, _) in enumerate(rows):
+        used = -(-n // page)
+        pt[b, :used] = np.arange(nxt, nxt + used)
+        scram[b, :used] = pt[b, :used]
+        nxt += used
+    lengths = jnp.asarray([n for n, _ in rows], jnp.int32)
     tail_k = jax.random.normal(ks[4], (bsz, kvh, page, d))
     tail_v = jax.random.normal(ks[5], (bsz, kvh, page, d))
-    tail_len = jnp.asarray([3, 1, page], jnp.int32)
+    tail_len = jnp.asarray([t for _, t in rows], jnp.int32)
 
-    got = ops.paged_attention_tail(q, pages, pt, lengths,
-                                   tail_k, tail_v, tail_len)
-    want = ref.paged_attention_tail_ref(q, pages, pt, lengths,
-                                        tail_k, tail_v, tail_len)
+    got = paged_attention_tail(q, pages, jnp.asarray(pt), lengths,
+                               tail_k, tail_v, tail_len,
+                               interpret=pltpu.InterpretParams())
+    want = ref.paged_attention_tail_ref(q, pages, jnp.asarray(scram),
+                                        lengths, tail_k, tail_v, tail_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=1e-5)
+
+def test_attention_walk_counters(small_model):
+    """Each decode dispatch counts the active rows' pages, and the padded
+    ``max_batch x PMAX`` table, once per layer."""
+    cfg, params = small_model
+    eng = PagedKVEngine(cfg, params, page_size=PAGE, n_pool_pages=96,
+                        max_batch=4)
+    eng.add_requests({0: list(range(1, 20)), 1: [4, 4, 8, 1],
+                      2: list(range(3, 45))})
+    eng.seqs[2].done = True                   # holds pages, not decoded
+    for _ in range(10):
+        before = eng.stats
+        pages = sum(len(eng.seqs[sid].pages[0]) for sid in (0, 1))
+        eng.decode_batch()
+        assert (eng.stats["attn_pages_read"] - before["attn_pages_read"]
+                == cfg.n_layers * pages)
+        assert (eng.stats["attn_pages_padded"]
+                - before["attn_pages_padded"]
+                == cfg.n_layers * 4 * eng._pmax)
+    assert pages > 2                          # a publish happened
