@@ -1,8 +1,9 @@
 """What decides ``correct``: the served tokens against the plain reference.
 
 After the window, a seeded sample of the requests that served tokens (the
-one with the most among them) is run through the float32 reference over
-its prompt and served tokens.  Each served token is greedy, so its
+one with the most among them) is run through the architecture's float32
+reference (``logits_at`` of ``bench/arch/<model_type>.py``) over its
+prompt and served tokens.  Each served token is greedy, so its
 reference logit should be the reference's best; the number compared is
 the widest gap by which a served token's logit lies below the best.  The
 control puts the same reference, computed in float8, in the program's
@@ -12,8 +13,6 @@ place and reads the gap of the token it ranks first.
 from __future__ import annotations
 
 import numpy as np
-
-from . import reference
 
 _ROW_BLOCK = 64
 
@@ -45,23 +44,23 @@ def _inputs(reqs):
     return seqs, rows
 
 
-def served_gaps(dims: dict, seed: int, reqs) -> np.ndarray:
+def served_gaps(arch, dims: dict, seed: int, reqs) -> np.ndarray:
     """Reference best logit minus the reference logit of each served
     token, over every served token of ``reqs``."""
     seqs, rows = _inputs(reqs)
     out = []
-    for r, lg in zip(reqs, reference.logits_at(dims, seed, seqs, rows)):
+    for r, lg in zip(reqs, arch.logits_at(dims, seed, seqs, rows)):
         lg = lg[:len(r.out)]
         out.append(lg.max(-1) - lg[np.arange(len(r.out)), r.out])
     return np.concatenate(out)
 
 
-def control_gaps(dims: dict, seed: int, reqs) -> np.ndarray:
+def control_gaps(arch, dims: dict, seed: int, reqs) -> np.ndarray:
     """The same gap for the token the float8 reference ranks first, at
     each position of the same prompts and served tokens."""
     seqs, rows = _inputs(reqs)
-    ref = reference.logits_at(dims, seed, seqs, rows)
-    low = reference.logits_at(dims, seed, seqs, rows, precision="fp8")
+    ref = arch.logits_at(dims, seed, seqs, rows)
+    low = arch.logits_at(dims, seed, seqs, rows, precision="fp8")
     out = []
     for r, a, b in zip(reqs, ref, low):
         a, b = a[:len(r.out)], b[:len(r.out)]

@@ -1,12 +1,13 @@
-"""The plain reference: a dense-GQA decoder forward in float32 jnp, QKV
-bias included, with no kernels, cache or batching.  It rebuilds the
-weights from the seed one layer at a time (``weights.one_layer``) and
-imports nothing of the program.
+"""What every architecture's plain reference (``bench/arch/<model_type>.py``
+``logits_at``) is built from: float32 jnp with no kernels, cache or
+batching, importing nothing of the program.
 
-Its equations are the program's model (``repro.models.transformer``):
-RMSNorm with a learned scale, rotary embedding on interleaved pairs
-(dims 2i, 2i+1), causal grouped-query attention (query head h reads KV
-head h // (H / K)), SwiGLU MLP, untied LM head.
+``by_layer`` runs a model layer by layer: each layer's weights are
+rebuilt alone from the seed, applied to every sequence and dropped, so
+that the reference fits beside what the run leaves on the device.  The
+operations an architecture's layer is written in are here too: matrix
+products, RMSNorm with a learned scale, rotary embedding on interleaved
+pairs (dims 2i, 2i+1), the embedding and the untied LM head.
 
 ``precision="fp8"`` is the control: every matrix product's operands are
 rounded to float8 e4m3 first, the step below the bf16 weights the
@@ -21,9 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import weights
-
-_Q_BLOCK = 512          # query rows per attention block
+Q_BLOCK = 512           # query rows per attention block
 _LEN_BLOCK = 512        # sequences pad to a multiple of this
 
 
@@ -33,16 +32,16 @@ def _round(x, precision):
     return x
 
 
-def _mm(a, b, eq, precision):
+def mm(a, b, eq, precision):
     return jnp.einsum(eq, _round(a, precision), _round(b, precision),
                       precision=jax.lax.Precision.HIGHEST)
 
 
-def _rmsnorm(w, x, eps):
+def rmsnorm(w, x, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x [T, N, Dh]; rotate interleaved pairs by position."""
     dh = x.shape[-1]
     inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
@@ -52,51 +51,18 @@ def _rope(x, pos, theta):
     return jnp.stack([x1 * c - x2 * s, x1 * s + x2 * c], -1).reshape(x.shape)
 
 
-def _lin(p, x, eq, precision):
-    y = _mm(x, p["w"].astype(jnp.float32), eq, precision)
+def lin(p, x, eq, precision):
+    y = mm(x, p["w"].astype(jnp.float32), eq, precision)
     if "b" in p:
         y = y + p["b"].astype(jnp.float32)
     return y
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "theta", "precision"))
-def block(p, x, *, eps, theta, precision):
-    """One decoder layer over one sequence: x f32 [T, D] -> [T, D]."""
-    t = x.shape[0]
-    pos = jnp.arange(t)
-    p = jax.tree.map(lambda a: a.astype(jnp.float32), p)
-    a = p["attn"]
-    h = _rmsnorm(p["ln1"]["w"], x, eps)
-    q = _rope(_lin(a["wq"], h, "td,dhe->the", precision), pos, theta)
-    k = _rope(_lin(a["wk"], h, "td,dke->tke", precision), pos, theta)
-    v = _lin(a["wv"], h, "td,dke->tke", precision)
-    n_h, n_kv, dh = q.shape[1], k.shape[1], q.shape[2]
-    q = q.reshape(t, n_kv, n_h // n_kv, dh) / jnp.sqrt(jnp.float32(dh))
-
-    def attend(i):
-        qb = jax.lax.dynamic_slice_in_dim(q, i * _Q_BLOCK, _Q_BLOCK)
-        s = _mm(qb, k, "qkgd,tkd->kgqt", precision)
-        qpos = i * _Q_BLOCK + jnp.arange(_Q_BLOCK)
-        s = jnp.where(pos[None, None, None, :] <= qpos[None, None, :, None],
-                      s, -jnp.inf)
-        w = jax.nn.softmax(s, axis=-1)
-        return _mm(w, v, "kgqt,tkd->qkgd", precision)
-
-    ctx = jax.lax.map(attend, jnp.arange(t // _Q_BLOCK))
-    ctx = ctx.reshape(t, n_h, dh)
-    x = x + _mm(ctx, a["wo"]["w"], "the,heD->tD", precision)
-    h = _rmsnorm(p["ln2"]["w"], x, eps)
-    f = p["ffn"]
-    g = jax.nn.silu(_lin(f["gate"], h, "td,df->tf", precision))
-    u = _lin(f["up"], h, "td,df->tf", precision)
-    return x + _mm(g * u, f["down"]["w"], "tf,fd->td", precision)
-
-
 @functools.partial(jax.jit, static_argnames=("eps", "precision"))
 def _logits(o, x, rows, *, eps, precision):
     o = jax.tree.map(lambda a: a.astype(jnp.float32), o)
-    h = _rmsnorm(o["final_norm"]["w"], x[rows], eps)
-    return _mm(h, o["lm_head"]["w"], "td,dv->tv", precision)
+    h = rmsnorm(o["final_norm"]["w"], x[rows], eps)
+    return mm(h, o["lm_head"]["w"], "td,dv->tv", precision)
 
 
 @jax.jit
@@ -104,25 +70,26 @@ def _embed(table, toks):
     return table[toks].astype(jnp.float32)
 
 
-def logits_at(dims: dict, seed: int, seqs: list[list[int]],
-              rows: list[list[int]], precision: str = "f32"
-              ) -> list[np.ndarray]:
+def by_layer(outer: dict, layer, n_layers: int, block,
+             seqs: list[list[int]], rows: list[list[int]], *, eps: float,
+             precision: str) -> list[np.ndarray]:
     """Logits [len(rows[i]), V] at positions ``rows[i]`` of each token
-    sequence ``seqs[i]``, computed layer by layer: each layer's weights
-    are rebuilt once and applied to every sequence."""
-    o = weights.outer_params(dims, seed)
+    sequence ``seqs[i]``.  ``outer`` holds the embedding, final norm and
+    LM head (``embed``, ``final_norm``, ``lm_head``); ``layer(li)``
+    rebuilds layer ``li``'s weights, and ``block(p, x)`` applies them to
+    one sequence, x f32 [T, D] with T padded to a multiple of 512."""
     xs = []
     for s in seqs:
         t = -(-len(s) // _LEN_BLOCK) * _LEN_BLOCK
-        xs.append(_embed(_round(o["embed"]["w"].astype(jnp.float32),
+        xs.append(_embed(_round(outer["embed"]["w"].astype(jnp.float32),
                                 precision),
                          jnp.asarray(np.pad(np.asarray(s, np.int32),
                                             (0, t - len(s))))))
-    for li in range(dims["n_layers"]):
-        p = weights.one_layer(dims, seed, li)
-        xs = [block(p, x, eps=dims["norm_eps"], theta=dims["rope_theta"],
-                    precision=precision) for x in xs]
+    for li in range(n_layers):
+        p = layer(li)
+        xs = [block(p, x) for x in xs]
         del p
-    return [np.asarray(_logits(o, x, jnp.asarray(np.asarray(r, np.int32)),
-                               eps=dims["norm_eps"], precision=precision))
+    return [np.asarray(_logits(outer, x,
+                               jnp.asarray(np.asarray(r, np.int32)),
+                               eps=eps, precision=precision))
             for x, r in zip(xs, rows)]

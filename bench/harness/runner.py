@@ -4,7 +4,6 @@ comparison that decides ``correct``."""
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -13,9 +12,9 @@ from types import SimpleNamespace
 
 import jax
 
-from . import correct, serve, stats, trace
+from . import correct, serve, spec, stats, trace
 from .peaks import peaks
-from .spec import BENCH, Cell, model_dims
+from .spec import BENCH, Cell
 
 ROOT = BENCH.parent
 TRACE_DIR = BENCH / ".trace"
@@ -35,12 +34,8 @@ def metrics_for(bench: dict, cell: str, kind: str) -> list[dict]:
 
 def reader(name: str):
     """The per-layer metric's own reader, ``bench/metrics/<name>.py``."""
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return spec.load_module(BENCH / "metrics" / f"{name}.py",
+                            "bench_metric").read
 
 
 def device_info(devs) -> dict:
@@ -103,7 +98,7 @@ def serve_window(cell: Cell, seed: int, seconds: float, *, trace_on: bool,
         d.reqs[rid].reason = reason
     rec = SimpleNamespace(
         reqs=reqs, steps=d.steps, seconds=seconds, setup_s=setup_s,
-        dims=model_dims(cell.config), page=st.eng.page, cell=cell,
+        arch=cell.arch, dims=cell.dims, page=st.eng.page, cell=cell,
         counters={k: c1[k] - c0.get(k, 0) for k in c1},
         compiles_in_window=n, memory_peak_bytes=memory_peak(devs),
         waiting=(waiting_open, waiting_close),
@@ -126,11 +121,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, *, trace_on: bool,
            "device": dict(device_info(devs),
                           memory_peak_bytes=rec.memory_peak_bytes)}
     if trace_on:
-        from bench.roofline import paged_attention
         rec.peaks = peaks(out["device"]["kind"])
-        rec.trace = trace.reduce(
-            trace.events(str(TRACE_DIR)),
-            {"paged_attention": paged_attention.KERNEL_OP})
+        rec.trace = trace.reduce(trace.events(str(TRACE_DIR)),
+                                 spec.kernel_ops(cell.root))
         if rec.trace is not None:
             out["device"]["busy_s"] = rec.trace["busy_s"]
             out["device"]["window_s"] = rec.trace["window_s"]
@@ -163,7 +156,7 @@ def check(cell: Cell, seed: int, rec, n_bad: int, log=print,
     picked = correct.sample(rec.reqs, seed, chk["sample_tokens"])
     t0 = time.perf_counter()
     gaps = correct.control_gaps if control else correct.served_gaps
-    gap = (float(gaps(rec.dims, seed, picked).max())
+    gap = (float(gaps(rec.arch, rec.dims, seed, picked).max())
            if picked else float("inf"))
     log(f"reference over {len(picked)} requests, "
         f"{sum(len(r.out) for r in picked)} served tokens, "

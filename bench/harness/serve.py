@@ -29,8 +29,8 @@ from repro.serving.engine import PagedKVEngine
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import ContinuousScheduler
 
-from . import traffic, weights
-from .spec import Cell, model_dims
+from . import traffic
+from .spec import Cell
 
 WARM_SID = 1 << 40          # warm-up request ids, clear of the window's
 WARM_STREAM = 0x3A4B        # the fixed stream warm-up tokens come from
@@ -97,20 +97,10 @@ class Stack:
     sched: ContinuousScheduler
 
 
-def arch_config(cell: Cell) -> ArchConfig:
-    d = model_dims(cell.config)
-    return ArchConfig(name=cell.cell["config"], family="dense",
-                      n_layers=d["n_layers"], d_model=d["d_model"],
-                      n_heads=d["n_heads"], n_kv_heads=d["n_kv_heads"],
-                      d_ff=d["d_ff"], vocab=d["vocab"],
-                      head_dim=d["head_dim"], qkv_bias=d["qkv_bias"],
-                      rope_theta=d["rope_theta"], norm_eps=d["norm_eps"])
-
-
 def build(cell: Cell, seed: int) -> Stack:
-    cfg = arch_config(cell)
+    cfg = cell.arch.arch_config(cell.cell["config"], cell.dims)
     e = cell.cell["engine"]
-    params = weights.model(model_dims(cell.config), seed)
+    params = cell.arch.model(cell.dims, seed)
     cache = (PrefixCache.for_model(cfg, e["page_size"])
              if cell.cell["prefix_cache"] else None)
     eng = PagedKVEngine(cfg, params, page_size=e["page_size"],

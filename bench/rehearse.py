@@ -7,8 +7,12 @@ The programs are the decode step at the widest page table the traffic
 reaches and the mixed step (decode plus a prefill chunk) at the largest
 cohort rows and scratch length of the cell's shape plan.  For each it
 prints ``memory_analysis()`` in bytes, beside the weights, the page pool
-and the chip's capacity: the numbers ``max_batch``, ``n_pool_pages``,
-``prefill_chunk`` and ``n_pool_pages`` are chosen from.
+and the chip's capacity: the numbers ``max_batch``, ``prefill_chunk`` and
+``n_pool_pages`` are chosen from.  The parameter shapes are the
+architecture module's (``bench/arch/<model_type>.py``); the page pool, the
+decode tails and the cohort scratch are the dense engine's, KV heads by
+head size per token.  A configuration whose pages hold something else,
+such as a latent row, is sized by a rehearsal of its own.
 """
 
 from __future__ import annotations
@@ -47,7 +51,7 @@ def main(argv=None) -> int:
     from repro import codecs
     from repro.serving import engine as E
 
-    from bench.harness import serve, spec, weights
+    from bench.harness import serve, spec
 
     backend.default_interpret = lambda: False     # compile the kernels
     jax.config.update("jax_enable_compilation_cache", False)
@@ -55,8 +59,7 @@ def main(argv=None) -> int:
     if args.max_batch:
         cell.cell["engine"]["max_batch"] = args.max_batch
     plan = serve.shape_plan(cell)
-    cfg = serve.arch_config(cell)
-    dims = spec.model_dims(cell.config)
+    cfg = cell.arch.arch_config(cell.cell["config"], cell.dims)
     e = cell.cell["engine"]
     codec = codecs.resolve(cell.cell["codec"])
     dev = topologies.get_topology_desc(
@@ -67,7 +70,7 @@ def main(argv=None) -> int:
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one), tree)
 
-    params = sds(jax.eval_shape(lambda: weights.model(dims, 0)))
+    params = sds(jax.eval_shape(lambda: cell.arch.model(cell.dims, 0)))
     pools = sds(jax.eval_shape(lambda: codec.init_pools(
         e["n_pool_pages"], cfg.n_kv_heads, e["page_size"], cfg.head_dim)))
     lyr, b = cfg.n_layers, e["max_batch"]

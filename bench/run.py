@@ -6,7 +6,8 @@ With ``--control 1`` the float8 reference's choices stand in for the
 served tokens in the comparison, so ``correct`` has to come out false.
 
 The cell is ``bench/cells/<cell>.json``; it names its model configuration
-(``bench/configs/``) and traffic mix (``bench/traffic/``).  The metrics it
+(``bench/configs/``, whose ``model_type`` names its architecture module
+under ``bench/arch/``) and traffic mix (``bench/traffic/``).  The metrics it
 reports are those ``BENCHMARK.json`` lists for it: the end-to-end ones
 with ``--trace 0``, the per-layer ones (read by ``bench/metrics/<name>.py``
 from a profiler trace of the window) with ``--trace 1``.  The last line

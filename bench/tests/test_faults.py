@@ -66,7 +66,7 @@ def test_float8_control_fails_the_limit(tmp_path, seed):
     assert ctl["logit_gap"]["value"] > limit
     picked = correct.sample(rec.reqs, seed, 10000)
     assert ctl["logit_gap"]["value"] == \
-        correct.control_gaps(rec.dims, seed, picked).max()
+        correct.control_gaps(rec.arch, rec.dims, seed, picked).max()
 
 
 def test_control_run_is_not_correct(tmp_path):

@@ -5,16 +5,17 @@ import pathlib
 
 import pytest
 
+from bench.arch import llama
+from bench.harness import spec
 from bench.harness.peaks import PEAKS, peaks
-from bench.harness.spec import model_dims
-from bench.roofline import model, paged_attention
+from bench.roofline import paged_attention
 
 BENCH = pathlib.Path(__file__).resolve().parents[1]
 
 
 def dims(name):
-    return model_dims(json.loads((BENCH / "configs" / f"{name}.json")
-                                 .read_text()))
+    config = json.loads((BENCH / "configs" / f"{name}.json").read_text())
+    return spec.load_arch(config).dims_of(config)
 
 
 YI, QWEN = dims("yi-6b"), dims("qwen2.5-14b-d12")
@@ -60,13 +61,13 @@ def test_bound_is_bandwidth_for_decode_attention():
 
 def test_model_flops_by_hand():
     lp = 4096 * 4096 + 2 * 4096 * 512 + 4096 * 4096 + 3 * 4096 * 11008
-    assert model.layer_params(YI) == lp
-    assert model.decode_flops(100, YI) == \
+    assert llama.layer_params(YI) == lp
+    assert llama.decode_flops(100, YI) == \
         2 * (32 * lp + 4096 * 64000) + 32 * 4 * 100 * 32 * 128
     # prefill of positions 0..9: no LM head, attention over 1..10
-    assert model.prefill_flops(10, 45, YI) == \
+    assert llama.prefill_flops(10, 45, YI) == \
         2 * 10 * 32 * lp + 32 * 4 * 55 * 32 * 128
-    q = model.layer_params(QWEN)
+    q = llama.layer_params(QWEN)
     assert q == 5120 * 5120 * 2 + 2 * 5120 * 1024 + 3 * 5120 * 13824
 
 

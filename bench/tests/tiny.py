@@ -36,10 +36,13 @@ CELLS = {
 
 
 def root(tmp_path: pathlib.Path, *names: str) -> pathlib.Path:
-    """A benchmark tree holding the tiny config and mixes, with the named
-    cells added as files."""
+    """A benchmark tree holding the tiny config and mixes and the
+    benchmark's architecture modules, with the named cells added as
+    files."""
     r = tmp_path / "bench"
     shutil.copytree(DATA, r)
+    shutil.copytree(spec.BENCH / "arch", r / "arch",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (r / "cells").mkdir()
     for n in names:
         (r / "cells" / f"{n}.json").write_text(json.dumps(CELLS[n]))
